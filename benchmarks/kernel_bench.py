@@ -38,6 +38,7 @@ from repro.kernels import binarize_pack as bp
 from repro.kernels import fused_bnn as fb
 from repro.kernels import paged_attention as pa
 from repro.kernels import xnor_popcount as xp
+from repro.launch.compile_cache import enable_compile_cache
 from repro.layers import attention as attn_mod
 from repro.layers import attn_block
 
@@ -248,6 +249,7 @@ def main() -> None:
                     help="validate an existing bench JSON and exit "
                          "(CI schema gate; no benchmark is run)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.check_json:
         problems = check_bench_json(args.check_json)

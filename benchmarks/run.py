@@ -16,6 +16,9 @@ import sys
 def main() -> None:
     from benchmarks import bnn_ablation, fig7_comparison, kernel_bench, \
         roofline, table2_scalability
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     sections = [
         ("table2", table2_scalability.run),

@@ -53,6 +53,7 @@ import numpy as np
 
 from repro import configs
 from repro.configs.base import reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as M
 from repro.serving import (Engine, EngineConfig, SamplingParams,
                            ShardedEngine, layer_layouts, nearest_rank,
@@ -676,6 +677,7 @@ def main():
                     help="validate an existing bench JSON and exit "
                          "(CI schema gate; no benchmark is run)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.check_json:
         problems = check_bench_json(args.check_json)

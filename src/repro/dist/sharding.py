@@ -93,32 +93,32 @@ def replicated(mesh) -> NamedSharding:
     return NamedSharding(mesh, PartitionSpec())
 
 
-def shard_meshes(n_shards: int, *, mesh=None, devices=None):
-    """Split the data axis into ``n_shards`` per-shard decode meshes.
+def shard_meshes(n_shards: int, *, devices=None):
+    """One 1x1 ("data", "model") decode mesh per shard.
 
-    Each shard gets a single-pod ("data", "model") mesh over a disjoint
-    slice of the parent mesh's devices (or of ``devices`` /
-    ``jax.devices()`` when no parent mesh is given).  With fewer
-    physical devices than shards — the single-process test case — the
-    device list is tiled round-robin; shard isolation (pools, jit
-    caches, indexes) comes from each shard's own Engine instance, not
-    from the mesh, so sharing a device under
-    ``xla_force_host_platform_device_count`` simulation keeps the same
-    semantics: shards are isolation domains first, hardware second.
+    Shards take distinct devices of ``devices`` (``jax.devices()`` by
+    default) whenever there are at least ``n_shards`` of them.  With
+    fewer CPU devices — the single-process test case — the list is
+    tiled round-robin: shard isolation (pools, jit caches, indexes)
+    comes from each shard's own Engine instance, not from the mesh, so
+    sharing a host device keeps the same semantics.  Accelerators are
+    never tiled: two shards on one chip would only contend for it.
     """
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")
     from jax.sharding import Mesh
     import numpy as np
 
-    if devices is None:
-        devices = (list(mesh.devices.flat) if mesh is not None
-                   else list(jax.devices()))
+    devices = list(jax.devices() if devices is None else devices)
     if len(devices) >= n_shards:
         per = len(devices) // n_shards
         leads = [devices[i * per] for i in range(n_shards)]
-    else:
+    elif devices[0].platform == "cpu":
         leads = [devices[i % len(devices)] for i in range(n_shards)]
+    else:
+        raise ValueError(f"{n_shards} shards need {n_shards} "
+                         f"{devices[0].platform} devices, "
+                         f"found {len(devices)}")
     # one PRIMARY device per shard: the engine datapath is single-device
     # within a shard (params pinned, pools donated in place), so each
     # shard's mesh is 1x1 over its lead — model-parallel-within-shard

@@ -4,17 +4,18 @@
 pallas calls: the packed activation matrix round-tripped through HBM
 between the comparator and the GEMM.  This kernel fuses the whole BNN
 chain — the float activation tile is binarized against the threshold
-and packed into uint32 words in VMEM registers, then XNOR'd/popcounted
-against the (pre-packed, weight-stationary) weight tile in the same
-grid step.  Packed activations never exist in HBM, matching the paper's
-datapath where the PCA comparator feeds the next layer's OXG operand
-drive directly (Sec. IV-C; cf. XNORBIN's fused binarize-convolve loop,
-arXiv:1803.05849).
+and packed into words in VMEM (``binarize_pack.pack_tile``), then
+XNOR'd/popcounted against the (pre-packed, weight-stationary) weight
+tile in the same grid step.  Packed activations never exist in HBM,
+matching the paper's datapath where the PCA comparator feeds the next
+layer's OXG operand drive directly (Sec. IV-C; cf. XNORBIN's fused
+binarize-convolve loop, arXiv:1803.05849).
 
-Same grid/accumulator/epilogue structure as kernels/xnor_popcount.py
-(the (bm, bn) int32 VMEM accumulator revisited across the K grid dim =
-the PCA photo-charge), so the two kernels stay differentially
-comparable; only the activation operand arrives unpacked.
+Same grid/accumulator/epilogue as kernels/xnor_popcount.py (shared
+``gemm_call``: the (bm, bn) int32 VMEM accumulator revisited across the
+K grid dim = the PCA photo-charge), so the two kernels stay
+differentially comparable; only the activation operand arrives
+unpacked.
 
 Weights stay a packed (N, Kw) uint32 operand: they are static across
 forwards, so packing them once per weight identity (kernels/ops.py
@@ -29,7 +30,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import binarize_pack as _bp
+from repro.kernels import xnor_popcount as _xp
 
 Array = jax.Array
 
@@ -40,60 +43,24 @@ DEFAULT_BN = 128
 DEFAULT_BK = 64   # packed words per K step (= 2048 float elements of x)
 
 
-def _popcount_u32(x):
-    x = x - ((x >> 1) & jnp.uint32(0x55555555))
-    x = (x & jnp.uint32(0x33333333)) + ((x >> 2) & jnp.uint32(0x33333333))
-    x = (x + (x >> 4)) & jnp.uint32(0x0F0F0F0F)
-    return ((x * jnp.uint32(0x01010101)) >> 24).astype(jnp.int32)
-
-
 def _fused_bnn_kernel(x_ref, wp_ref, alpha_ref, out_ref, acc_ref, *,
-                      s: int, kw: int, bk: int, mode: str,
-                      threshold: float, inner_chunk: int):
+                      s: int, mode: str, threshold: float):
     """One (m, n, k) grid step: binarize+pack x tile, XNOR-popcount it
     against the packed weight tile, accumulate in VMEM scratch."""
     k = pl.program_id(2)
-    nk = pl.num_programs(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # ---- fused operand drive: comparator + pack, in registers ----
-    x = x_ref[...]                               # (bm, bk*32) float
-    bm = x.shape[0]
-    bits = (x >= threshold).astype(jnp.uint32)
-    bits = bits.reshape(bm, bk, WORD_BITS)
-    shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)[None, None, :]
-    ip = jnp.sum(bits << shifts, axis=-1).astype(jnp.uint32)   # (bm, bk)
+    # fused operand drive: comparator + pack, never leaving VMEM
+    ip = _bp.pack_tile(x_ref[...], threshold)        # (bm, bk) int32
+    acc_ref[...] = _xp.accumulate_xor_popcount(acc_ref[...], ip,
+                                               wp_ref[...])
 
-    wp = wp_ref[...]                             # (bn, bk) uint32
-
-    def body(c, acc):
-        i_blk = jax.lax.dynamic_slice_in_dim(ip, c * inner_chunk,
-                                             inner_chunk, 1)
-        w_blk = jax.lax.dynamic_slice_in_dim(wp, c * inner_chunk,
-                                             inner_chunk, 1)
-        xnor = ~(i_blk[:, None, :] ^ w_blk[None, :, :])
-        return acc + jnp.sum(_popcount_u32(xnor), axis=-1, dtype=jnp.int32)
-
-    acc_ref[...] = jax.lax.fori_loop(0, bk // inner_chunk, body,
-                                     acc_ref[...])
-
-    @pl.when(k == nk - 1)
+    @pl.when(k == pl.num_programs(2) - 1)
     def _epilogue():
-        z = acc_ref[...] - (kw * WORD_BITS - s)  # pad correction
-        if mode == "bitcount":
-            out_ref[...] = z
-        elif mode == "dot":
-            out_ref[...] = 2 * z - s
-        elif mode == "dot_scaled":
-            dot = (2 * z - s).astype(jnp.float32)
-            out_ref[...] = dot * alpha_ref[...][None, :]
-        elif mode == "binary_act":
-            out_ref[...] = (z > s / 2).astype(jnp.int32)
-        else:
-            raise ValueError(mode)
+        out_ref[...] = _xp.epilogue(acc_ref[...], alpha_ref[...], s, mode)
 
 
 def fused_bnn_matmul(x: Array, wp: Array, s: int, *,
@@ -103,7 +70,6 @@ def fused_bnn_matmul(x: Array, wp: Array, s: int, *,
                      bm: int = DEFAULT_BM,
                      bn: int = DEFAULT_BN,
                      bk: int = DEFAULT_BK,
-                     inner_chunk: int = 8,
                      interpret: bool | None = None) -> Array:
     """Fused binarize(x) @ unpack(wp).T in one kernel: (M, S) float x
     (N, Kw) packed -> (M, N).
@@ -114,68 +80,23 @@ def fused_bnn_matmul(x: Array, wp: Array, s: int, *,
     """
     m, sx = x.shape
     assert sx == s, (sx, s)
-    n, kw = wp.shape
+    kw = wp.shape[1]
     assert kw == -(-s // WORD_BITS), (kw, s)
-    if alpha is None:
-        alpha = jnp.ones((n,), jnp.float32)
-    assert alpha.shape == (n,)
 
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
     bm = min(bm, m)
-    bn = min(bn, n)
     bk = min(bk, kw)
-    inner_chunk = min(inner_chunk, bk)
-    while bk % inner_chunk:
-        inner_chunk -= 1
 
     # pad x with sub-threshold values (-> 0 bits, same as the packed
-    # weight's zero tail) so the shared kw-based pad correction holds
+    # weight's zero tail) so pad bits never disagree
     pad_m = (-m) % bm
     pad_s = (-s) % (bk * WORD_BITS)
     xp = jnp.pad(x.astype(jnp.float32), ((0, pad_m), (0, pad_s)),
                  constant_values=threshold - 1.0)
-    mp, sp = xp.shape
-    kwp = sp // WORD_BITS
-
-    def padto(a, b, axis):
-        pad = (-a.shape[axis]) % b
-        if pad == 0:
-            return a
-        widths = [(0, 0)] * a.ndim
-        widths[axis] = (0, pad)
-        return jnp.pad(a, widths)
-
-    # bk divides kwp and kw <= kwp, so padding the word axis to a bk
-    # multiple lands the weight operand on exactly x's padded width
-    wp_p = padto(padto(wp, bn, 0), bk, 1)
-    alpha_p = padto(alpha, bn, 0)
-    np_ = wp_p.shape[0]
-
-    out_dtype = jnp.float32 if mode == "dot_scaled" else jnp.int32
-    kernel = functools.partial(
-        _fused_bnn_kernel, s=s, kw=kwp, bk=bk, mode=mode,
-        threshold=threshold, inner_chunk=inner_chunk)
-
-    grid = (mp // bm, np_ // bn, kwp // bk)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk * WORD_BITS), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(xp, wp_p, alpha_p)
-
-    out = out[:m, :n]
-    if mode == "binary_act":
-        out = out.astype(jnp.uint8)
-    return out
+    kernel = functools.partial(_fused_bnn_kernel, s=s, mode=mode,
+                               threshold=threshold)
+    return _xp.gemm_call(kernel, xp, bk * WORD_BITS, wp, alpha, m=m,
+                         kwp=xp.shape[1] // WORD_BITS, mode=mode,
+                         bm=bm, bn=bn, bk=bk, interpret=interpret)

@@ -64,18 +64,27 @@ def resolve_impl(impl: str = "auto") -> str:
     return impl
 
 
+def _dot_nt(a, b):
+    """(m, d) x (n, d) -> (m, n) in float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _paged_attn_kernel(
         # scalar-prefetch refs (available to index maps AND the body)
         tab_ref, kvlen_ref, qoff_ref, newest_ref,
         # tensor refs (block-sliced per grid step)
         q_ref, pool_a_ref, pool_b_ref, *rest,
         layout: str, ring: bool, causal: bool, window: int | None,
-        bs: int, mb: int, hkv: int, nope_dim: int, v_dim: int):
+        bs: int, mb: int, c: int, nope_dim: int, v_dim: int):
     """One (batch_row, logical_block) grid step of the template.
 
-    Scratch (m, l, acc) carries the online-softmax state across the
-    row's block walk — the same revisit-in-VMEM pattern as the XNOR
-    kernel's photo-charge accumulator.
+    q arrives head-major, (Hkv, G*C, Dq): the G query heads that share
+    one KV head are stacked as row blocks of C, so every score and
+    value product is a plain 2D matmul per KV head (no rank-4 einsum,
+    no sublane reshape).  Scratch (m, l, acc) carries the online-softmax
+    state across the row's block walk — the same revisit-in-VMEM
+    pattern as the XNOR kernel's photo-charge accumulator.
     """
     if layout == "mla":
         k_up_ref, v_up_ref, o_ref, m_ref, l_ref, acc_ref = rest
@@ -90,9 +99,8 @@ def _paged_attn_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)            # (C, H, Dq)
-    c, h, dq = q.shape
-    qf = q * (dq ** -0.5)
+    hkv, gc, dq = q_ref.shape[1:]
+    scale = dq ** -0.5
 
     # ---- per-slot absolute key positions + mask (all 2D iota: TPU) ----
     slots = i * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
@@ -103,67 +111,54 @@ def _paged_attn_kernel(
         kpos = slots                            # (1, bs)
     mask = (kpos >= 0) & (kpos < kvlen_ref[b])
     if causal or (window is not None and window > 0):
-        qpos = qoff_ref[b] + jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+        # row r of a head block is query position q_offset + r mod C
+        row = jax.lax.broadcasted_iota(jnp.int32, (gc, 1), 0)
+        qpos = qoff_ref[b] + jax.lax.rem(row, c)
         if causal:
             mask = mask & (qpos >= kpos)
         if window is not None and window > 0:
             mask = mask & (qpos - kpos < window)
-    mask = jnp.broadcast_to(mask, (c, bs))      # (C, BS)
+    mask = jnp.broadcast_to(mask, (gc, bs))     # (G*C, BS)
 
-    # ---- layout-specialized K/V for this physical block ----
     if layout == "mla":
         lat = pool_a_ref[0].astype(jnp.float32)         # (BS, R)
         rope = pool_b_ref[0].astype(jnp.float32)        # (BS, Dr)
-        # in-kernel latent decompression (the MLA memory win: HBM only
-        # ever sees the compressed latents)
-        k_nope = jnp.dot(lat, k_up_ref[...],
-                         preferred_element_type=jnp.float32)
-        k_nope = k_nope.reshape(bs, h, nope_dim)
-        v = jnp.dot(lat, v_up_ref[...],
-                    preferred_element_type=jnp.float32)
-        v = v.reshape(bs, h, v_dim)
-        scores = (jnp.einsum("chd,shd->chs", qf[..., :nope_dim], k_nope,
-                             preferred_element_type=jnp.float32)
-                  + jnp.einsum("chd,sd->chs", qf[..., nope_dim:], rope,
-                               preferred_element_type=jnp.float32))
-    else:
-        k = pool_a_ref[0].astype(jnp.float32)           # (BS, Hkv, Dh)
-        v = pool_b_ref[0].astype(jnp.float32)           # (BS, Hkv, Dv)
-        g = h // hkv
-        scores = jnp.einsum("ckgd,skd->ckgs",
-                            qf.reshape(c, hkv, g, dq), k,
-                            preferred_element_type=jnp.float32)
-        scores = scores.reshape(c, h, bs)
 
-    scores = jnp.where(mask[:, None, :], scores, NEG_INF)   # (C, H, BS)
+    for h in range(hkv):
+        q = q_ref[0, h].astype(jnp.float32) * scale     # (G*C, Dq)
+        # ---- layout-specialized K/V of this head for this block ----
+        if layout == "mla":
+            # in-kernel latent decompression (the MLA memory win: HBM
+            # only ever sees the compressed latents)
+            k = jnp.dot(lat, k_up_ref[:, h * nope_dim:(h + 1) * nope_dim],
+                        preferred_element_type=jnp.float32)
+            v = jnp.dot(lat, v_up_ref[:, h * v_dim:(h + 1) * v_dim],
+                        preferred_element_type=jnp.float32)
+            scores = (_dot_nt(q[:, :nope_dim], k)
+                      + _dot_nt(q[:, nope_dim:], rope))
+        else:
+            k = pool_a_ref[0, :, h, :].astype(jnp.float32)  # (BS, Dh)
+            v = pool_b_ref[0, :, h, :].astype(jnp.float32)  # (BS, Dv)
+            scores = _dot_nt(q, k)
+        scores = jnp.where(mask, scores, NEG_INF)           # (G*C, BS)
 
-    # ---- online-softmax merge with the running (m, l, acc) ----
-    m_prev = m_ref[...]                                      # (C, H)
-    l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
-    # explicit mask (not exp of NEG_INF-NEG_INF): a fully-masked block
-    # with m_new still at NEG_INF would otherwise contribute exp(0)=1
-    p = jnp.where(mask[:, None, :], jnp.exp(scores - m_new[..., None]), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-    if layout == "mla":
-        pv = jnp.einsum("chs,shd->chd", p, v,
-                        preferred_element_type=jnp.float32)
-    else:
-        g = h // hkv
-        pv = jnp.einsum("ckgs,skd->ckgd", p.reshape(c, hkv, g, bs), v,
-                        preferred_element_type=jnp.float32)
-        pv = pv.reshape(c, h, v.shape[-1])
-    acc_new = acc_ref[...] * alpha[..., None] + pv
-    m_ref[...] = m_new
-    l_ref[...] = l_new
-    acc_ref[...] = acc_new
+        # ---- online-softmax merge with the running (m, l, acc) ----
+        m_prev = m_ref[h]                                   # (G*C, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        # explicit mask (not exp of NEG_INF-NEG_INF): a fully-masked
+        # block with m_new still at NEG_INF would otherwise add exp(0)=1
+        p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
 
     @pl.when(i == mb - 1)
     def _finalize():
         # fully-masked rows: l stayed 0 -> output 0 (flash semantics)
         l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention(q: Array, pool_a: Array, pool_b: Array,
@@ -215,16 +210,21 @@ def paged_attention(q: Array, pool_a: Array, pool_b: Array,
         assert not ring, "ring layout needs per-row `newest` positions"
         newest = jnp.zeros((b,), jnp.int32)
 
+    g = h // hkv
     kernel = functools.partial(
         _paged_attn_kernel, layout=layout, ring=ring, causal=causal,
-        window=window, bs=bs, mb=mb, hkv=hkv, nope_dim=nope_dim,
-        v_dim=v_dim)
+        window=window, bs=bs, mb=mb, c=c, nope_dim=nope_dim, v_dim=v_dim)
+
+    # head-major query rows: (B, C, Hkv*G, Dq) -> (B, Hkv, G*C, Dq), so
+    # the kernel sees one 2D (G*C, Dq) operand per KV head
+    qh = q.reshape(b, c, hkv, g, dq).transpose(0, 2, 3, 1, 4)
+    qh = qh.reshape(b, hkv, g * c, dq)
 
     # scalar-prefetched operands feed the pool index maps: the kernel
     # sees exactly one physical block per grid step, chosen by the
     # row's block table — the in-kernel gather.
     in_specs = [
-        pl.BlockSpec((1, c, h, dq), lambda bi, i, *s: (bi, 0, 0, 0)),
+        pl.BlockSpec((1, hkv, g * c, dq), lambda bi, i, *s: (bi, 0, 0, 0)),
         pl.BlockSpec(
             (1, bs) + pool_a.shape[2:],
             lambda bi, i, tab, *s: (tab[bi, i],) + (0,) * (pool_a.ndim - 1)),
@@ -232,7 +232,7 @@ def paged_attention(q: Array, pool_a: Array, pool_b: Array,
             (1, bs) + pool_b.shape[2:],
             lambda bi, i, tab, *s: (tab[bi, i],) + (0,) * (pool_b.ndim - 1)),
     ]
-    args = [q, pool_a, pool_b]
+    args = [qh, pool_a, pool_b]
     if layout == "mla":
         in_specs += [
             pl.BlockSpec(k_up.shape, lambda bi, i, *s: (0, 0)),
@@ -246,15 +246,15 @@ def paged_attention(q: Array, pool_a: Array, pool_b: Array,
             num_scalar_prefetch=4,
             grid=(b, mb),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, c, h, v_dim),
+            out_specs=pl.BlockSpec((1, hkv, g * c, v_dim),
                                    lambda bi, i, *s: (bi, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((c, h), jnp.float32),          # running max
-                pltpu.VMEM((c, h), jnp.float32),          # running sum
-                pltpu.VMEM((c, h, v_dim), jnp.float32),   # weighted acc
+                pltpu.VMEM((hkv, g * c, 1), jnp.float32),      # running max
+                pltpu.VMEM((hkv, g * c, 1), jnp.float32),      # running sum
+                pltpu.VMEM((hkv, g * c, v_dim), jnp.float32),  # weighted acc
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, c, h, v_dim), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g * c, v_dim), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
@@ -263,4 +263,5 @@ def paged_attention(q: Array, pool_a: Array, pool_b: Array,
       jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (b,)),
       jnp.broadcast_to(jnp.asarray(newest, jnp.int32), (b,)),
       *args)
-    return out
+    out = out.reshape(b, hkv, g, c, v_dim).transpose(0, 3, 1, 2, 4)
+    return out.reshape(b, c, h, v_dim)

@@ -11,19 +11,21 @@ Design (HW adaptation of the XPC, see DESIGN.md):
     bitcounts accumulate IN PLACE, never touching HBM — the exact TPU
     analogue of the PCA holding charge across PASSes (no psum
     reduction network, paper Sec. IV-C).
-  * The epilogue (pad correction + {-1,+1} rescale + LQ-Nets alpha scale
-    or the paper's comparator activation) is fused into the final K step
+  * The epilogue (agreeing-bit count + {-1,+1} rescale + LQ-Nets alpha
+    scale or the paper's comparator activation) is fused into the final K step
     — the analogue of the PCA's comparator producing the next layer's
     activation before anything is written back.
 
 The kernel is VPU work (integer xor/popcount/add); MXU is not used.
-Block defaults keep every operand tile lane-aligned (multiples of 128 in
-the minor dim where possible) and the working set in VMEM:
-  ip tile (bm, bk)*4B + wp tile (bn, bk)*4B + acc (bm, bn)*4B
-  = 128*256*4 + 128*256*4 + 128*128*4 ~= 0.33 MB  << 16 MB VMEM.
+The weight operand enters word-major, (Kw, N): each packed word of the
+activation tile is a (bm, 1) column broadcast along lanes against one
+(1, bn) weight row, so every XOR/popcount runs on a full (bm, bn) tile.
+The kernel counts DISAGREEING bits, popcount(a ^ w): pad bits are zero
+in both operands, so z = s - count needs no pad correction.
 
 Validated on CPU via interpret=True against ref.py across shape/dtype
-sweeps (tests/test_xnor_kernel.py).
+sweeps (tests/test_xnor_kernel.py) and compiled for the TPU
+(tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -40,58 +42,105 @@ WORD_BITS = 32
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
-DEFAULT_BK = 256  # packed words per K step (= 8192 binary elements)
+DEFAULT_BK = 128  # packed words per K step (= 4096 binary elements)
+
+MODES = ("bitcount", "dot", "dot_scaled", "binary_act")
 
 
-def _popcount_u32(x):
-    x = x - ((x >> 1) & jnp.uint32(0x55555555))
-    x = (x & jnp.uint32(0x33333333)) + ((x >> 2) & jnp.uint32(0x33333333))
-    x = (x + (x >> 4)) & jnp.uint32(0x0F0F0F0F)
-    return ((x * jnp.uint32(0x01010101)) >> 24).astype(jnp.int32)
+def accumulate_xor_popcount(acc: Array, ip: Array, wp_t: Array) -> Array:
+    """acc (bm, bn) int32 + sum_k popcount(ip[:, k] ^ wp_t[k, :]) for an
+    int32 activation tile ip (bm, bk) and weight tile wp_t (bk, bn)."""
+    for k in range(ip.shape[1]):
+        acc = acc + jax.lax.population_count(ip[:, k:k + 1] ^ wp_t[k:k + 1, :])
+    return acc
+
+
+def epilogue(xor_count: Array, alpha: Array, s: int, mode: str) -> Array:
+    """Readout of the accumulated disagreement count; alpha is (1, bn)."""
+    z = s - xor_count                       # agreeing bits
+    if mode == "bitcount":
+        return z
+    if mode == "dot":
+        return 2 * z - s
+    if mode == "dot_scaled":
+        return (2 * z - s).astype(jnp.float32) * alpha
+    if mode == "binary_act":
+        return (2 * z > s).astype(jnp.int32)
+    raise ValueError(mode)
+
+
+def gemm_call(kernel, operand, block_cols: int, wp: Array,
+              alpha: Array | None, *, m: int, kwp: int, mode: str,
+              bm: int, bn: int, bk: int, interpret: bool) -> Array:
+    """Shared pallas_call for the packed GEMMs: ``operand`` is the padded
+    (mp, .) activation array, ``block_cols`` wide per K block of ``bk``
+    words (``kwp`` padded words in all); ``wp`` is the (N, Kw) packed
+    weight, transposed here to (Kw, N)."""
+    if mode not in MODES:
+        raise ValueError(mode)
+    n = wp.shape[0]
+    if alpha is None:
+        alpha = jnp.ones((n,), jnp.float32)
+    assert alpha.shape == (n,)
+    mp = operand.shape[0]
+    bn = min(bn, n)
+
+    def padto(a, b, axis):
+        pad = (-a.shape[axis]) % b
+        if pad == 0:
+            return a
+        widths = [(0, 0)] * a.ndim
+        widths[axis] = (0, pad)
+        return jnp.pad(a, widths)
+
+    # pad words are zero, like the activation side's, so they never
+    # disagree; word-major layout puts N on lanes
+    wp_t = jax.lax.bitcast_convert_type(wp, jnp.int32).T
+    wp_t = padto(padto(wp_t, kwp, 0), bn, 1)
+    alpha_p = padto(alpha.astype(jnp.float32).reshape(1, n), bn, 1)
+    np_ = wp_t.shape[1]
+
+    out_dtype = jnp.float32 if mode == "dot_scaled" else jnp.int32
+    out = pl.pallas_call(
+        kernel,
+        grid=(mp // bm, np_ // bn, kwp // bk),
+        in_specs=[
+            pl.BlockSpec((bm, block_cols), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(operand, wp_t, alpha_p)
+
+    out = out[:m, :n]
+    if mode == "binary_act":
+        out = out.astype(jnp.uint8)
+    return out
 
 
 def _xnor_popcount_kernel(ip_ref, wp_ref, alpha_ref, out_ref, acc_ref, *,
-                          s: int, kw: int, bk: int, mode: str,
-                          inner_chunk: int):
+                          s: int, mode: str):
     """One (m, n, k) grid step.
 
     acc_ref: VMEM scratch (bm, bn) int32 — the 'photo-charge' accumulator.
     """
     k = pl.program_id(2)
-    nk = pl.num_programs(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ip = ip_ref[...]  # (bm, bk) uint32
-    wp = wp_ref[...]  # (bn, bk) uint32
+    acc_ref[...] = accumulate_xor_popcount(acc_ref[...], ip_ref[...],
+                                           wp_ref[...])
 
-    # Accumulate popcount(XNOR) over the word axis in chunks, so the
-    # (bm, bn, chunk) intermediate stays small in VMEM/VREGs.
-    def body(c, acc):
-        i_blk = jax.lax.dynamic_slice_in_dim(ip, c * inner_chunk, inner_chunk, 1)
-        w_blk = jax.lax.dynamic_slice_in_dim(wp, c * inner_chunk, inner_chunk, 1)
-        xnor = ~(i_blk[:, None, :] ^ w_blk[None, :, :])
-        return acc + jnp.sum(_popcount_u32(xnor), axis=-1, dtype=jnp.int32)
-
-    acc = jax.lax.fori_loop(0, bk // inner_chunk, body, acc_ref[...])
-    acc_ref[...] = acc
-
-    @pl.when(k == nk - 1)
+    @pl.when(k == pl.num_programs(2) - 1)
     def _epilogue():
-        z = acc_ref[...] - (kw * WORD_BITS - s)  # pad correction
-        if mode == "bitcount":
-            out_ref[...] = z
-        elif mode == "dot":
-            out_ref[...] = 2 * z - s
-        elif mode == "dot_scaled":
-            dot = (2 * z - s).astype(jnp.float32)
-            out_ref[...] = dot * alpha_ref[...][None, :]
-        elif mode == "binary_act":
-            out_ref[...] = (z > s / 2).astype(jnp.int32)
-        else:
-            raise ValueError(mode)
+        out_ref[...] = epilogue(acc_ref[...], alpha_ref[...], s, mode)
 
 
 def xnor_popcount_matmul(ip: Array, wp: Array, s: int, *,
@@ -100,7 +149,6 @@ def xnor_popcount_matmul(ip: Array, wp: Array, s: int, *,
                          bm: int = DEFAULT_BM,
                          bn: int = DEFAULT_BN,
                          bk: int = DEFAULT_BK,
-                         inner_chunk: int = 8,
                          interpret: bool | None = None) -> Array:
     """Packed XNOR-bitcount GEMM: (M, Kw) x (N, Kw) -> (M, N).
 
@@ -108,63 +156,14 @@ def xnor_popcount_matmul(ip: Array, wp: Array, s: int, *,
     contraction length in bits.  See module docstring for modes.
     """
     m, kw = ip.shape
-    n, kw2 = wp.shape
-    assert kw == kw2, (kw, kw2)
-    if alpha is None:
-        alpha = jnp.ones((n,), jnp.float32)
-    assert alpha.shape == (n,)
-
+    assert wp.shape[1] == kw, (kw, wp.shape)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
     bm = min(bm, m)
-    bn = min(bn, n)
     bk = min(bk, kw)
-    inner_chunk = min(inner_chunk, bk)
-    while bk % inner_chunk:
-        inner_chunk -= 1
-
-    # pad to block multiples (pad words are zero in both operands: their
-    # XNOR contributes to the pad correction already accounted via kw)
-    def padto(x, b, axis):
-        pad = (-x.shape[axis]) % b
-        if pad == 0:
-            return x
-        widths = [(0, 0)] * x.ndim
-        widths[axis] = (0, pad)
-        return jnp.pad(x, widths)
-
-    ip_p = padto(padto(ip, bm, 0), bk, 1)
-    wp_p = padto(padto(wp, bn, 0), bk, 1)
-    alpha_p = padto(alpha, bn, 0)
-    mp, kwp = ip_p.shape
-    np_, _ = wp_p.shape
-
-    out_dtype = jnp.float32 if mode == "dot_scaled" else jnp.int32
-    # NOTE kw passed to the kernel must be the PADDED word count, since the
-    # padded tail words also contribute popcount(~(0^0)) = 32 each.
-    kernel = functools.partial(
-        _xnor_popcount_kernel, s=s, kw=kwp, bk=bk, mode=mode,
-        inner_chunk=inner_chunk)
-
-    grid = (mp // bm, np_ // bn, kwp // bk)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(ip_p, wp_p, alpha_p)
-
-    out = out[:m, :n]
-    if mode == "binary_act":
-        out = out.astype(jnp.uint8)
-    return out
+    ip_i = jax.lax.bitcast_convert_type(ip, jnp.int32)
+    ip_p = jnp.pad(ip_i, ((0, (-m) % bm), (0, (-kw) % bk)))
+    kernel = functools.partial(_xnor_popcount_kernel, s=s, mode=mode)
+    return gemm_call(kernel, ip_p, bk, wp, alpha, m=m, kwp=ip_p.shape[1],
+                     mode=mode, bm=bm, bn=bn, bk=bk, interpret=interpret)

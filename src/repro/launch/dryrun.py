@@ -1,8 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# NOTE: the two lines above MUST precede every other import (jax locks the
-# device count on first init), which is why the docstring sits below them.
+# NOTE: the lines above MUST precede every other import (jax locks the
+# device count and platform on first init), which is why the docstring
+# sits below them.  The 512 devices are forced HOST devices: pinning the
+# CPU platform keeps a machine with a TPU from handing the mesh its chip.
 DOC = """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware:

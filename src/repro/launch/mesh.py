@@ -1,8 +1,8 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A FUNCTION (not a module-level constant) so importing this module never
-touches jax device state — required for the smoke tests to keep seeing
-one device while the dry-run forces 512 host devices.
+FUNCTIONS (not module-level constants) so importing this module never
+touches jax device state — required for the tests to keep seeing one
+device while the dry-run forces 512 host devices.
 """
 from __future__ import annotations
 
@@ -32,8 +32,13 @@ def make_mesh_for(devices, *, multi_pod: bool = False):
     return Mesh(arr, axes)
 
 
-def smoke_mesh():
-    """1x1 mesh over the single CPU device (tests)."""
+def device_mesh(devices=None):
+    """("data", "model") mesh of shape (n, 1) over the devices present
+    (``jax.devices()`` by default): one chip gives a 1x1 mesh.  Serving
+    and training build their mesh here; the fixed 16x16 pod meshes
+    above are only for the dry-run's forced host devices."""
     from jax.sharding import Mesh
     import numpy as np
-    return Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    devices = list(jax.devices() if devices is None else devices)
+    return Mesh(np.asarray(devices, dtype=object).reshape(len(devices), 1),
+                ("data", "model"))

@@ -29,9 +29,13 @@ teacher-forced logprob/ppl scoring requests alongside generation.
 scheduler policy (latency vs throughput classes, per-tenant token
 budgets — serving/policy.py) and assigns requests round-robin.
 
-Usage (CPU smoke):
+Usage (CPU smoke, reduced widths):
   PYTHONPATH=src python -m repro.launch.serve --arch bnn-lm-100m --smoke \
       --batch 4 --prompt-len 16 --gen 16 --precision bnn
+Usage (one TPU chip, published widths; --shards N puts each shard on
+its own device when N devices exist):
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
+      --precision bnn
 """
 from __future__ import annotations
 
@@ -45,7 +49,8 @@ import numpy as np
 
 from repro import configs
 from repro.configs.base import reduced
-from repro.launch.mesh import make_production_mesh, smoke_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import device_mesh
 from repro.dist import sharding as S
 from repro.layers import common as C
 from repro.models import transformer as M
@@ -53,18 +58,19 @@ from repro.serving import (Engine, EngineConfig, Frontend, SamplingParams,
                            parse_tenants, tenants_arg)
 
 
-def _setup(arch, smoke, multi_pod, precision, seed):
+def _setup(arch, smoke, precision, seed):
+    """Config (``reduced`` widths under ``smoke``), random params and
+    the 1x1 mesh of the first device present: an engine's datapath is
+    single-device (ShardedEngine places each shard on its own)."""
     cfg = configs.get_config(arch)
     if smoke:
         cfg = reduced(cfg)
-        mesh = smoke_mesh()
-    else:
-        mesh = make_production_mesh(multi_pod=multi_pod)
     if precision:
         cfg = cfg.replace(precision=precision)
-    C.set_sharding_context(mesh, S.rules_decode(multi_pod))
+    C.set_sharding_context(device_mesh(jax.devices()[:1]),
+                           S.rules_decode(False))
     params, _ = M.init(jax.random.PRNGKey(seed), cfg)
-    return cfg, params, mesh
+    return cfg, params
 
 
 def _prompts(cfg, batch, prompt_len, seed):
@@ -72,13 +78,13 @@ def _prompts(cfg, batch, prompt_len, seed):
                               (batch, prompt_len), 0, cfg.vocab)
 
 
-def serve_legacy(arch: str, *, smoke: bool = False, multi_pod: bool = False,
+def serve_legacy(arch: str, *, smoke: bool = False,
                  batch: int = 4, prompt_len: int = 16, gen: int = 16,
                  precision: str | None = None, seed: int = 0,
                  greedy: bool = True):
     """Reference loop: batched dense-slot cache, token-by-token prefill."""
     try:
-        cfg, params, _ = _setup(arch, smoke, multi_pod, precision, seed)
+        cfg, params = _setup(arch, smoke, precision, seed)
         max_len = prompt_len + gen
         caches = M.init_cache(cfg, batch, max_len)
         prompts = _prompts(cfg, batch, prompt_len, seed)
@@ -166,7 +172,7 @@ def _serve_stream(eng, prompts, gen, sampling, *, tenants, score,
     return rids, out
 
 
-def serve(arch: str, *, smoke: bool = False, multi_pod: bool = False,
+def serve(arch: str, *, smoke: bool = False,
           batch: int = 4, prompt_len: int = 16, gen: int = 16,
           precision: str | None = None, seed: int = 0,
           greedy: bool = True, engine: str = "paged",
@@ -202,11 +208,11 @@ def serve(arch: str, *, smoke: bool = False, multi_pod: bool = False,
     tenants.  Uncancelled streamed output is byte-identical to the
     batch path for the same flags."""
     if engine == "legacy":
-        return serve_legacy(arch, smoke=smoke, multi_pod=multi_pod,
+        return serve_legacy(arch, smoke=smoke,
                             batch=batch, prompt_len=prompt_len, gen=gen,
                             precision=precision, seed=seed, greedy=greedy)
     try:
-        cfg, params, mesh = _setup(arch, smoke, multi_pod, precision, seed)
+        cfg, params = _setup(arch, smoke, precision, seed)
         max_len = prompt_len + gen
         bs = block_size or max(8, min(32, prompt_len))
         if policy is None:
@@ -228,7 +234,7 @@ def serve(arch: str, *, smoke: bool = False, multi_pod: bool = False,
             from repro.serving import ShardedEngine
             eng = ShardedEngine(
                 params, cfg, ecfg, shards,
-                meshes=S.shard_meshes(shards, mesh=mesh),
+                meshes=S.shard_meshes(shards),
                 rules=S.rules_decode(False), roles=roles)
         else:
             eng = Engine(params, cfg, ecfg)
@@ -344,8 +350,9 @@ def serve(arch: str, *, smoke: bool = False, multi_pod: bool = False,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="bnn-lm-100m")
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced() widths (CPU tests); default: the "
+                         "published widths")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=16)
@@ -420,7 +427,8 @@ def main():
                     help="cancel the last request after this many "
                          "streamed tokens (requires --stream)")
     args = ap.parse_args()
-    serve(args.arch, smoke=args.smoke, multi_pod=args.multi_pod,
+    enable_compile_cache()
+    serve(args.arch, smoke=args.smoke,
           batch=args.batch, prompt_len=args.prompt_len, gen=args.gen,
           precision=args.precision, engine=args.engine,
           block_size=args.block_size, prefill_chunk=args.prefill_chunk,

@@ -1,9 +1,9 @@
 """Training launcher: mesh + sharded step + checkpoint/restore loop.
 
-Runs the same code path at every scale:
-  * CPU smoke (tests/examples):  --smoke  (1x1 mesh, reduced config)
-  * production pod:              16x16 mesh  (default)
-  * multi-pod:                   --multi-pod (2x16x16)
+The mesh is built from the devices present (launch/mesh.device_mesh:
+(n, 1) over ("data", "model"), a 1x1 mesh on one chip); --smoke only
+swaps in the reduced() widths the CPU tests use.  The fixed 16x16 pod
+meshes belong to the compile-only dry-run (launch/dryrun.py).
 
 Fault tolerance: resume-from-latest is automatic; on a device failure
 the runbook in repro/dist/fault.py applies (re-mesh over survivors via
@@ -28,13 +28,14 @@ from repro.configs.base import reduced
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.dist import sharding as S
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import make_production_mesh, smoke_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import device_mesh
 from repro.layers import common as C
 from repro.models import transformer as M
 from repro.optim import optimizer as opt_mod
 
 
-def train(arch: str, *, smoke: bool = False, multi_pod: bool = False,
+def train(arch: str, *, smoke: bool = False,
           steps: int = 50, global_batch: int = 8, seq_len: int = 128,
           microbatches: int = 1, ckpt_dir: str | None = None,
           ckpt_every: int = 20, lr: float = 3e-4, log_every: int = 10,
@@ -43,13 +44,11 @@ def train(arch: str, *, smoke: bool = False, multi_pod: bool = False,
     cfg = configs.get_config(arch)
     if smoke:
         cfg = reduced(cfg)
-        mesh = smoke_mesh()
-    else:
-        mesh = make_production_mesh(multi_pod=multi_pod)
     if precision:
         cfg = cfg.replace(precision=precision)
+    mesh = device_mesh()
 
-    rules = S.rules_train(multi_pod, fsdp=not smoke)
+    rules = S.rules_train(False, fsdp=not smoke)
     C.set_sharding_context(mesh, rules)
     try:
         params, specs = M.init(jax.random.PRNGKey(seed), cfg)
@@ -102,8 +101,8 @@ def train(arch: str, *, smoke: bool = False, multi_pod: bool = False,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="bnn-lm-100m")
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced() widths (CPU tests)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=128)
@@ -113,7 +112,8 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--precision", default=None)
     args = ap.parse_args()
-    train(args.arch, smoke=args.smoke, multi_pod=args.multi_pod,
+    enable_compile_cache()
+    train(args.arch, smoke=args.smoke,
           steps=args.steps, global_batch=args.global_batch,
           seq_len=args.seq_len, microbatches=args.microbatches,
           ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every, lr=args.lr,

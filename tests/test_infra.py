@@ -209,11 +209,53 @@ def test_param_shardings_tree(tmp_path):
     from repro import configs
     from repro.configs.base import reduced
     from repro.models import transformer as M
-    from repro.launch.mesh import smoke_mesh
+    from repro.launch.mesh import device_mesh
 
     cfg = reduced(configs.get_config("mixtral-8x7b"))
     shapes, specs = M.abstract_init(cfg)
-    mesh = smoke_mesh()
+    mesh = device_mesh()
     shards = S.param_shardings(mesh, shapes, specs, S.rules_train(False))
     # same tree structure
     assert jax.tree.structure(shapes) == jax.tree.structure(shards)
+
+
+def test_device_mesh_covers_devices_present():
+    from repro.launch.mesh import device_mesh
+
+    mesh = device_mesh()
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.devices.shape == (len(jax.devices()), 1)
+    assert device_mesh(jax.devices()[:1]).devices.shape == (1, 1)
+
+
+def test_compile_cache_dir_rule(monkeypatch):
+    """An outside JAX_COMPILATION_CACHE_DIR is left alone; without one
+    the cache goes to the checkout's fixed .jax_cache/."""
+    from repro.launch import compile_cache as cc
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "outside-dir")
+        assert cc.enable_compile_cache() == "outside-dir"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = cc.enable_compile_cache()
+        assert path == os.path.join(cc.ROOT, ".jax_cache")
+        assert os.path.isfile(os.path.join(cc.ROOT, "chip_smoke.py"))
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_chip_smoke_refuses_cpu():
+    """With no TPU the chip smoke run exits non-zero with no result line
+    instead of falling back to the CPU."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, os.path.join(root, "chip_smoke.py")],
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode != 0
+    assert "no TPU found" in r.stderr
+    assert '"ok"' not in r.stdout

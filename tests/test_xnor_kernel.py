@@ -23,8 +23,8 @@ SHAPES = [
     (3, 512, 96),
 ]
 
-BLOCKS = [dict(bm=32, bn=32, bk=4, inner_chunk=2),
-          dict(bm=128, bn=128, bk=64, inner_chunk=8)]
+BLOCKS = [dict(bm=32, bn=32, bk=4),
+          dict(bm=128, bn=128, bk=64)]
 
 
 @pytest.mark.parametrize("m,n,s", SHAPES)
