@@ -85,5 +85,6 @@ def binarize_pack(x: Array, *, threshold: float = 0.0,
         out_specs=pl.BlockSpec((bm, bkw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, kwp), jnp.int32),
         interpret=interpret,
+        name="bnn_binarize_pack",
     )(xp)
     return jax.lax.bitcast_convert_type(out[:m, :kw], jnp.uint32)

@@ -109,11 +109,12 @@ def _pack_weight(w: Array, impl: str, scale: bool
     """(N, Kw) packed transpose of w plus its LQ-Nets alpha column
     scales; cached per concrete array identity (weakref-evicted)."""
     def compute():
-        alpha = jnp.mean(jnp.abs(w), axis=0) if scale else None
-        if impl == "pallas":
-            wp = _bp.binarize_pack(w.astype(jnp.float32).T)
-        else:
-            wp = jnp.swapaxes(packing.pack_pm1(w, axis=0), 0, 1)
+        with jax.named_scope("weight_pack"):
+            alpha = jnp.mean(jnp.abs(w), axis=0) if scale else None
+            if impl == "pallas":
+                wp = _bp.binarize_pack(w.astype(jnp.float32).T)
+            else:
+                wp = jnp.swapaxes(packing.pack_pm1(w, axis=0), 0, 1)
         return wp, alpha
 
     if isinstance(w, jax.core.Tracer):

@@ -258,6 +258,7 @@ def paged_attention(q: Array, pool_a: Array, pool_b: Array,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention",
     )(block_table.astype(jnp.int32),
       kv_len.astype(jnp.int32),
       jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (b,)),

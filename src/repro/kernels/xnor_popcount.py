@@ -115,6 +115,7 @@ def gemm_call(kernel, operand, block_cols: int, wp: Array,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="bnn_xnor_gemm",
     )(operand, wp_t, alpha_p)
 
     out = out[:m, :n]

@@ -12,6 +12,10 @@ Tracks (load the output at https://ui.perfetto.dev or chrome://tracing):
                             (swap_out / swap_in / snapshot_out /
                             snapshot_in / handoff_out / handoff_in)
                             with block/byte counts;
+  * ``engine / phases``   — the phases of each step (schedule, then
+                            prefill / decode / spec prepare, dispatch,
+                            sync, commit), args carrying ``step``,
+                            ``parent`` and the call's rows or tokens;
   * ``requests / rid N``  — per-request lifecycle: a ``queued`` slice
                             from submit to admit, ``running`` from
                             admit to finish (or swap_out), ``swapped``
@@ -22,8 +26,8 @@ Tracks (load the output at https://ui.perfetto.dev or chrome://tracing):
 Merged multi-shard mode: a ``ShardedEngine`` writes one trace per
 shard (``{prefix}.shard{i}.jsonl``).  Pointing this tool at the prefix
 (or any one shard file with ``--merge-shards``) merges them into ONE
-timeline with a process per worker ROLE (prefill / decode / mixed — a
-thread pair per shard inside it), clocks aligned via each tracer's
+timeline with a process per worker ROLE (prefill / decode / mixed —
+steps, copies and phases threads per shard inside it), clocks aligned via each tracer's
 ``t0`` meta anchor, and every prefill->decode handoff rendered as a
 flow arrow from the source's ``handoff_out`` span to the destination's
 ``handoff_in`` span (paired by ``handoff_id``).
@@ -49,6 +53,8 @@ ENGINE_PID = 1
 REQUEST_PID = 2
 STEP_TID = 1
 COPY_TID = 2
+PHASE_TID = 3
+PHASES = ("schedule", "prepare", "dispatch", "sync", "commit")
 
 _US = 1e6  # trace_event timestamps are microseconds
 
@@ -76,10 +82,17 @@ def _instant(pid, tid, name, ts_s, args=None):
     return ev
 
 
+def is_phase(name: str) -> bool:
+    """A step phase span (``schedule``, ``decode.sync``, ...) rather
+    than a copy."""
+    return name.rsplit(".", 1)[-1] in PHASES
+
+
 def _engine_tracks(events, records, *, pid, step_tid, copy_tid,
-                   ts_off=0.0) -> float:
-    """Step + copy-span slices for one engine's records onto (pid,
-    tids); returns the last timestamp seen (trace-end watermark)."""
+                   phase_tid, ts_off=0.0) -> float:
+    """Step, copy-span and phase-span slices for one engine's records
+    onto (pid, tids); returns the last timestamp seen (trace-end
+    watermark)."""
     last_ts = 0.0
     for rec in records:
         t = rec["type"]
@@ -95,7 +108,8 @@ def _engine_tracks(events, records, *, pid, step_tid, copy_tid,
             # span ts is the scope's START
             args = {k: v for k, v in rec.items()
                     if k not in ("type", "ts", "dur_s", "name")}
-            events.append(_slice(pid, copy_tid, rec["name"],
+            tid = phase_tid if is_phase(rec["name"]) else copy_tid
+            events.append(_slice(pid, tid, rec["name"],
                                  rec["ts"] + ts_off, rec["dur_s"], args))
             last_ts = max(last_ts, rec["ts"] + ts_off + rec["dur_s"])
     return last_ts
@@ -158,10 +172,12 @@ def to_trace_events(records: list[dict]) -> dict:
         _meta_event(ENGINE_PID, 0, "process_name", "engine"),
         _meta_event(ENGINE_PID, STEP_TID, "thread_name", "steps"),
         _meta_event(ENGINE_PID, COPY_TID, "thread_name", "copies"),
+        _meta_event(ENGINE_PID, PHASE_TID, "thread_name", "phases"),
         _meta_event(REQUEST_PID, 0, "process_name", "requests"),
     ]
     last_ts = _engine_tracks(events, records, pid=ENGINE_PID,
-                             step_tid=STEP_TID, copy_tid=COPY_TID)
+                             step_tid=STEP_TID, copy_tid=COPY_TID,
+                             phase_tid=PHASE_TID)
     by_rid: dict[int, list[dict]] = {}
     for rec in records:
         if rec["type"] == "request":
@@ -197,7 +213,7 @@ def discover_shard_traces(path: str) -> list[tuple[int, str]]:
 def to_merged_trace_events(shard_records: list[tuple[int, list[dict]]]) \
         -> dict:
     """Merge per-shard traces into ONE timeline: a process per worker
-    role (a steps/copies thread pair per shard inside it), one shared
+    role (steps, copies and phases threads per shard inside it), one shared
     requests process (the rid space is global), clocks aligned via the
     ``t0`` meta anchors, and handoff flow arrows between the prefill
     and decode tracks (``handoff_out`` -> ``handoff_in`` span pairs
@@ -224,15 +240,17 @@ def to_merged_trace_events(shard_records: list[tuple[int, list[dict]]]) \
     for i, records in shard_records:
         role = metas[i].get("role", "mixed")
         pid = role_pid[role]
-        step_tid, copy_tid = 2 * i + 1, 2 * i + 2
+        step_tid, copy_tid, phase_tid = 3 * i + 1, 3 * i + 2, 3 * i + 3
         tids[i] = (pid, step_tid, copy_tid)
         events.append(_meta_event(pid, step_tid, "thread_name",
                                   f"shard{i} steps"))
         events.append(_meta_event(pid, copy_tid, "thread_name",
                                   f"shard{i} copies"))
+        events.append(_meta_event(pid, phase_tid, "thread_name",
+                                  f"shard{i} phases"))
         last_ts = max(last_ts, _engine_tracks(
             events, records, pid=pid, step_tid=step_tid,
-            copy_tid=copy_tid, ts_off=offs[i]))
+            copy_tid=copy_tid, phase_tid=phase_tid, ts_off=offs[i]))
     # one merged request timeline: shift each record onto the common
     # clock, then interleave by ts (a request's lifecycle crosses
     # shards on handoff/migration)

@@ -116,13 +116,14 @@ def scatter_blocks(pool: Array, block_table: Array, positions: Array,
     """
     nb, bs, *rest = pool.shape
     mb = block_table.shape[1]
-    bidx = positions // bs                                      # (B, C)
-    bidx = bidx % mb if ring else jnp.clip(bidx, 0, mb - 1)
-    phys = jnp.take_along_axis(block_table, bidx, axis=1)       # (B, C)
-    phys = jnp.where(valid, phys, 0)
-    offs = jnp.where(valid, positions % bs, 0)
-    return pool.at[phys.reshape(-1), offs.reshape(-1)].set(
-        values.reshape(-1, *rest).astype(pool.dtype))
+    with jax.named_scope("kv_update"):
+        bidx = positions // bs                                  # (B, C)
+        bidx = bidx % mb if ring else jnp.clip(bidx, 0, mb - 1)
+        phys = jnp.take_along_axis(block_table, bidx, axis=1)   # (B, C)
+        phys = jnp.where(valid, phys, 0)
+        offs = jnp.where(valid, positions % bs, 0)
+        return pool.at[phys.reshape(-1), offs.reshape(-1)].set(
+            values.reshape(-1, *rest).astype(pool.dtype))
 
 
 def ring_key_positions(newest: Array, mb: int, bs: int) -> Array:

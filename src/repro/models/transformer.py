@@ -431,16 +431,24 @@ def _iter_layers(cfg: ArchConfig, params):
 def _paged_ffn(params, cfg: ArchConfig, f: str, x, precision):
     if f == "none":
         return x
-    h = C.norm(x, params["norm2"], cfg.norm, cfg.norm_eps)
-    if f == "moe":
-        # drop-free routing: a finite capacity makes logits depend on
-        # chunk width / bucket padding (jamba divergence root cause)
-        y, _ = moe.forward(params["ffn"], h, top_k=cfg.top_k, kind=cfg.act,
-                           capacity_factor=0.0,
-                           precision=precision)
-    else:
-        y = ffn.forward(params["ffn"], h, cfg.act, precision)
-    return x + y
+    with jax.named_scope("ffn"):
+        h = C.norm(x, params["norm2"], cfg.norm, cfg.norm_eps)
+        if f == "moe":
+            # drop-free routing: a finite capacity makes logits depend on
+            # chunk width / bucket padding (jamba divergence root cause)
+            y, _ = moe.forward(params["ffn"], h, top_k=cfg.top_k,
+                               kind=cfg.act, capacity_factor=0.0,
+                               precision=precision)
+        else:
+            y = ffn.forward(params["ffn"], h, cfg.act, precision)
+        return x + y
+
+
+def _paged_head(params, cfg: ArchConfig, x):
+    """Final norm and the (tied) vocabulary projection."""
+    with jax.named_scope("head"):
+        x = C.norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+        return jnp.einsum("btd,dv->btv", x, _head_matrix(params, cfg))
 
 
 def paged_decode_step(params, cfg: ArchConfig, tokens: Array, caches,
@@ -461,26 +469,25 @@ def paged_decode_step(params, cfg: ArchConfig, tokens: Array, caches,
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     new_caches = []
     for li, (mix, f, p) in enumerate(_iter_layers(cfg, params)):
-        h = C.norm(x, p["norm1"], cfg.norm, cfg.norm_eps)
-        if mix == "gqa":
-            y, nc = attn_block.paged_decode_step(
-                p["attn"], cfg, h, caches[li], block_table, lengths,
-                precision=cfg.precision, active=active, ring=ring,
-                attn_impl=attn_impl)
-        elif mix == "mla":
-            y, nc = mla.paged_decode_step(
-                p["attn"], cfg, h, caches[li], block_table, lengths,
-                precision=cfg.precision, active=active, ring=ring,
-                attn_impl=attn_impl)
-        else:
-            y, nc = mamba2.paged_decode_step(
-                p["attn"], cfg, h, caches[li], slots,
-                precision=cfg.precision, active=active)
+        with jax.named_scope("attn"):
+            h = C.norm(x, p["norm1"], cfg.norm, cfg.norm_eps)
+            if mix == "gqa":
+                y, nc = attn_block.paged_decode_step(
+                    p["attn"], cfg, h, caches[li], block_table, lengths,
+                    precision=cfg.precision, active=active, ring=ring,
+                    attn_impl=attn_impl)
+            elif mix == "mla":
+                y, nc = mla.paged_decode_step(
+                    p["attn"], cfg, h, caches[li], block_table, lengths,
+                    precision=cfg.precision, active=active, ring=ring,
+                    attn_impl=attn_impl)
+            else:
+                y, nc = mamba2.paged_decode_step(
+                    p["attn"], cfg, h, caches[li], slots,
+                    precision=cfg.precision, active=active)
         new_caches.append(nc)
         x = _paged_ffn(p, cfg, f, x + y, cfg.precision)
-    x = C.norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    logits = jnp.einsum("btd,dv->btv", x, _head_matrix(params, cfg))
-    return logits, new_caches
+    return _paged_head(params, cfg, x), new_caches
 
 
 def prefill_chunk(params, cfg: ArchConfig, tokens: Array, caches,
@@ -501,26 +508,25 @@ def prefill_chunk(params, cfg: ArchConfig, tokens: Array, caches,
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     new_caches = []
     for li, (mix, f, p) in enumerate(_iter_layers(cfg, params)):
-        h = C.norm(x, p["norm1"], cfg.norm, cfg.norm_eps)
-        if mix == "gqa":
-            y, nc = attn_block.prefill_chunk(
-                p["attn"], cfg, h, caches[li], block_table, lengths,
-                n_valid, precision=cfg.precision, ring=ring,
-                attn_impl=attn_impl)
-        elif mix == "mla":
-            y, nc = mla.prefill_chunk(
-                p["attn"], cfg, h, caches[li], block_table, lengths,
-                n_valid, precision=cfg.precision, ring=ring,
-                attn_impl=attn_impl)
-        else:
-            y, nc = mamba2.prefill_chunk(
-                p["attn"], cfg, h, caches[li], slots, n_valid,
-                precision=cfg.precision)
+        with jax.named_scope("attn"):
+            h = C.norm(x, p["norm1"], cfg.norm, cfg.norm_eps)
+            if mix == "gqa":
+                y, nc = attn_block.prefill_chunk(
+                    p["attn"], cfg, h, caches[li], block_table, lengths,
+                    n_valid, precision=cfg.precision, ring=ring,
+                    attn_impl=attn_impl)
+            elif mix == "mla":
+                y, nc = mla.prefill_chunk(
+                    p["attn"], cfg, h, caches[li], block_table, lengths,
+                    n_valid, precision=cfg.precision, ring=ring,
+                    attn_impl=attn_impl)
+            else:
+                y, nc = mamba2.prefill_chunk(
+                    p["attn"], cfg, h, caches[li], slots, n_valid,
+                    precision=cfg.precision)
         new_caches.append(nc)
         x = _paged_ffn(p, cfg, f, x + y, cfg.precision)
-    x = C.norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    logits = jnp.einsum("btd,dv->btv", x, _head_matrix(params, cfg))
-    return logits, new_caches
+    return _paged_head(params, cfg, x), new_caches
 
 
 def snapshot_slot_state(cfg: ArchConfig, caches, slots: Array) -> list:

@@ -334,27 +334,33 @@ class Engine:
                 s.preempts, s.swap_losts, c.swap_outs, c.swap_ins)
 
     def step(self) -> bool:
-        """One engine iteration; False when nothing was schedulable."""
-        t0 = time.perf_counter()
+        """One engine iteration; False when nothing was schedulable.
+
+        Its phases are tracer spans (``schedule``, then per kind
+        ``prepare`` / ``dispatch`` / ``sync`` / ``commit``) inside the
+        root ``step`` span, each carrying this step's number."""
         step = self.step_count
         tr = self.tracer
-        if tr.enabled:
-            self._step_rec = {}
-            marks = self._counter_marks()
-        plan = self.scheduler.schedule(step)
-        if plan.prefill is not None:
-            self._run_prefill(step, plan.prefill, plan.prefill_tokens)
-        # prefill-side preemption may have requeued planned decode rows
-        decode = [r for r in plan.decode
-                  if r.state == State.DECODE and r in self.scheduler.running]
-        if decode:
-            if self._spec_k:
-                self._run_decode_spec(step, decode)
-            else:
-                self._run_decode(step, decode)
-        self.step_count += 1
-        dt = time.perf_counter() - t0
-        tr.add_time("step", dt)
+        tr.step = step
+        with tr.span("step") as root:
+            if tr.enabled:
+                self._step_rec = {}
+                marks = self._counter_marks()
+            with tr.span("schedule"):
+                plan = self.scheduler.schedule(step)
+            if plan.prefill is not None:
+                self._run_prefill(step, plan.prefill, plan.prefill_tokens)
+            # prefill-side preemption may have requeued planned decode rows
+            decode = [r for r in plan.decode
+                      if r.state == State.DECODE
+                      and r in self.scheduler.running]
+            if decode:
+                if self._spec_k:
+                    self._run_decode_spec(step, decode)
+                else:
+                    self._run_decode(step, decode)
+            self.step_count += 1
+        tr.step = self.step_count
         if tr.enabled:
             rec = self._step_rec
             self._step_rec = None
@@ -362,7 +368,7 @@ class Engine:
             keys = ("prefix_hits", "snapshot_hits", "preempts",
                     "swap_losts", "swap_outs", "swap_ins")
             actions = {k: d for k, d in zip(keys, delta) if d}
-            ev = {"type": "step", "step": step, "dur_s": dt,
+            ev = {"type": "step", "step": step, "dur_s": root.dur,
                   "kind": "+".join(
                       k for k in ("prefill", "decode", "spec_verify")
                       if k in rec) or "idle",
@@ -461,48 +467,63 @@ class Engine:
     # ------------------------------------------------------------ internals
 
     def _run_prefill(self, step: int, req: Request, chunk: int):
-        if not self.scheduler.grow_or_preempt(step, req, req.pos + chunk):
-            return                     # req itself was preempted
-        # copy-on-write: never scatter into a block another owner shares
-        # (the full-prefix-match case re-prefills its final token here)
-        for idx in self.cache.writable_indices(req.pos, chunk):
-            if not self.scheduler.make_writable(step, req, idx):
-                return
+        tr = self.tracer
         cp = self.ecfg.prefill_chunk   # fixed padded shape (no re-jit)
-        tokens = np.zeros((1, cp), np.int32)
-        tokens[0, :chunk] = req.prompt[req.pos:req.pos + chunk]
-        table = self.cache.table_rows([req], 1)
-        slots = self.cache.slot_rows([req], 1)
-        srows = sampling_rows([req], 1)
-        tok, _logits, pools = self._prefill_fn(
-            self.params, self.cache.pools, jnp.asarray(tokens),
-            jnp.asarray(table), jnp.asarray([req.pos], jnp.int32),
-            jnp.asarray([chunk], jnp.int32), jnp.asarray(slots),
-            *srows.as_args())
-        self.cache.pools = pools
-        if req.score:
-            # teacher-forced scoring: the chunk's logits rows predict
-            # prompt positions pos+1 .. pos+chunk (same capture path
-            # the tracer's capture_logits uses)
-            self._accumulate_score(
-                req, np.asarray(_logits[0, :chunk], np.float32), chunk)
-            self._score_passes += 1
-        req.pos += chunk
-        self._prefilled += chunk
-        self._prefill_calls += 1
-        self.cache.register_prefix(req)
-        self.scheduler._ev(step, "prefill", req.rid, tokens=chunk,
-                           pos=req.pos)
-        if self._step_rec is not None:
-            info = {"rid": req.rid, "tokens": chunk, "pos": req.pos,
-                    "prompt_len": req.prompt_len}
+        stats = {"rid": req.rid, "tokens": chunk, "chunk": cp}
+        with tr.span("prefill.prepare", **stats):
+            if not self.scheduler.grow_or_preempt(step, req,
+                                                  req.pos + chunk):
+                return                 # req itself was preempted
+            # copy-on-write: never scatter into a block another owner
+            # shares (the full-prefix-match case re-prefills its final
+            # token here)
+            for idx in self.cache.writable_indices(req.pos, chunk):
+                if not self.scheduler.make_writable(step, req, idx):
+                    return
+            tokens = np.zeros((1, cp), np.int32)
+            tokens[0, :chunk] = req.prompt[req.pos:req.pos + chunk]
+            table = self.cache.table_rows([req], 1)
+            slots = self.cache.slot_rows([req], 1)
+            srows = sampling_rows([req], 1)
+            args = (jnp.asarray(tokens), jnp.asarray(table),
+                    jnp.asarray([req.pos], jnp.int32),
+                    jnp.asarray([chunk], jnp.int32), jnp.asarray(slots),
+                    *srows.as_args())
+        with tr.span("prefill.dispatch", **stats):
+            tok, _logits, pools = self._prefill_fn(
+                self.params, self.cache.pools, *args)
+            self.cache.pools = pools
+        done = req.pos + chunk == req.prompt_len
+        if req.score or done:
+            with tr.span("prefill.sync", **stats):
+                if req.score:
+                    # teacher-forced scoring: the chunk's logits rows
+                    # predict prompt positions pos+1 .. pos+chunk (same
+                    # capture path the tracer's capture_logits uses)
+                    score_rows = np.asarray(_logits[0, :chunk], np.float32)
+                else:
+                    first = int(np.asarray(tok)[0])
+        with tr.span("prefill.commit", **stats):
             if req.score:
-                info["score"] = True
-            if self.tracer.capture_logits:
-                info["logits"] = np.asarray(
-                    _logits[0, :chunk], np.float32).tolist()
-            self._step_rec["prefill"] = info
-        if req.pos == req.prompt_len:
+                self._accumulate_score(req, score_rows, chunk)
+                self._score_passes += 1
+            req.pos += chunk
+            self._prefilled += chunk
+            self._prefill_calls += 1
+            self.cache.register_prefix(req)
+            self.scheduler._ev(step, "prefill", req.rid, tokens=chunk,
+                               pos=req.pos)
+            if self._step_rec is not None:
+                info = {"rid": req.rid, "tokens": chunk, "pos": req.pos,
+                        "prompt_len": req.prompt_len}
+                if req.score:
+                    info["score"] = True
+                if self.tracer.capture_logits:
+                    info["logits"] = np.asarray(
+                        _logits[0, :chunk], np.float32).tolist()
+                self._step_rec["prefill"] = info
+            if not done:
+                return
             if req.score:
                 # scoring never decodes: the request finishes straight
                 # out of its last prefill chunk, releasing its state
@@ -513,7 +534,7 @@ class Engine:
                 req.finish_s = req.first_token_s
                 self._commit(req, True)
                 return
-            req.out.append(int(np.asarray(tok)[0]))
+            req.out.append(first)
             req.state = State.DECODE
             req.first_token_step = step
             req.first_token_s = time.perf_counter()
@@ -577,52 +598,65 @@ class Engine:
         return [r for r in ready
                 if r in self.scheduler.running and r.state == State.DECODE]
 
+    def _planned(self, reqs: list[Request]) -> dict:
+        """Span stats of a decode call's planned rows and bucket (the
+        prepare phase may still drop rows to preemption)."""
+        return {"rows": len(reqs),
+                "bucket": min(self._bucket(len(reqs)), self.ecfg.max_batch)}
+
     def _run_decode(self, step: int, reqs: list[Request]):
-        ready = self._ready_rows(step, reqs, lambda r: 1)
-        if not ready:
-            return
-        bucket = min(self._bucket(len(ready)), self.ecfg.max_batch)
-        tokens = np.zeros((bucket, 1), np.int32)
-        lengths = np.zeros(bucket, np.int32)
-        active = np.zeros(bucket, bool)
-        for i, r in enumerate(ready):
-            tokens[i, 0] = r.last_token
-            lengths[i] = r.pos
-            active[i] = True
-        table = self.cache.table_rows(ready, bucket)
-        slots = self.cache.slot_rows(ready, bucket)
-        srows = sampling_rows(ready, bucket)
-        next_tok, _dec_logits, pools = self._decode_fn(
-            self.params, self.cache.pools, jnp.asarray(tokens),
-            jnp.asarray(table), jnp.asarray(lengths), jnp.asarray(active),
-            jnp.asarray(slots), *srows.as_args())
-        self.cache.pools = pools
-        next_tok = np.asarray(next_tok)
-        self._max_concurrent = max(self._max_concurrent, len(ready))
-        self._decode_calls += 1
-        self._decode_rows += len(ready)
-        self._decode_produced += len(ready)
-        self.scheduler._ev(step, "decode", None,
-                           rids=[r.rid for r in ready], batch=bucket)
-        if self._step_rec is not None:
-            info = {"rows": len(ready), "bucket": bucket,
-                    "rids": [r.rid for r in ready],
-                    "fed_tokens": len(ready), "committed": len(ready)}
-            if self.tracer.capture_logits:
-                info["logits"] = np.asarray(
-                    _dec_logits[:len(ready), -1], np.float32).tolist()
-            self._step_rec["decode"] = info
-        now = time.perf_counter()
-        for i, r in enumerate(ready):
-            if r.state is not State.DECODE:
-                continue    # cancelled mid-loop by a commit callback
-            r.pos += 1
-            r.out.append(int(next_tok[i]))
-            self._decoded += 1
-            if r.done:
-                self.scheduler.finish(step, r)
-                r.finish_s = now
-            self._commit(r, r.done)
+        tr = self.tracer
+        with tr.span("decode.prepare", **self._planned(reqs)):
+            ready = self._ready_rows(step, reqs, lambda r: 1)
+            if not ready:
+                return
+            bucket = min(self._bucket(len(ready)), self.ecfg.max_batch)
+            tokens = np.zeros((bucket, 1), np.int32)
+            lengths = np.zeros(bucket, np.int32)
+            active = np.zeros(bucket, bool)
+            for i, r in enumerate(ready):
+                tokens[i, 0] = r.last_token
+                lengths[i] = r.pos
+                active[i] = True
+            table = self.cache.table_rows(ready, bucket)
+            slots = self.cache.slot_rows(ready, bucket)
+            srows = sampling_rows(ready, bucket)
+            args = (jnp.asarray(tokens), jnp.asarray(table),
+                    jnp.asarray(lengths), jnp.asarray(active),
+                    jnp.asarray(slots), *srows.as_args())
+        stats = {"rows": len(ready), "bucket": bucket}
+        with tr.span("decode.dispatch", **stats):
+            next_tok, _dec_logits, pools = self._decode_fn(
+                self.params, self.cache.pools, *args)
+            self.cache.pools = pools
+        with tr.span("decode.sync", **stats):
+            next_tok = np.asarray(next_tok)
+        with tr.span("decode.commit", **stats):
+            self._max_concurrent = max(self._max_concurrent, len(ready))
+            self._decode_calls += 1
+            self._decode_rows += len(ready)
+            self._decode_produced += len(ready)
+            self.scheduler._ev(step, "decode", None,
+                               rids=[r.rid for r in ready], batch=bucket)
+            if self._step_rec is not None:
+                info = {"rows": len(ready), "bucket": bucket,
+                        "rids": [r.rid for r in ready],
+                        "fed_tokens": len(ready), "committed": len(ready)}
+                if self.tracer.capture_logits:
+                    info["logits"] = np.asarray(
+                        _dec_logits[:len(ready), -1], np.float32).tolist()
+                self._step_rec["decode"] = info
+            now = time.perf_counter()
+            for i, r in enumerate(ready):
+                if r.state is not State.DECODE:
+                    continue    # cancelled mid-loop by a commit callback
+                r.pos += 1
+                r.out.append(int(next_tok[i]))
+                self._decoded += 1
+                if r.done:
+                    self.scheduler.finish(step, r)
+                    r.finish_s = now
+                self._commit(r, r.done)
 
     # ------------------------------------------------- speculative decode
 
@@ -640,49 +674,70 @@ class Engine:
             drafts[r.rid] = d
             return len(d) + 1
 
-        ready = self._ready_rows(step, reqs, lookahead)
-        if not ready:
-            return
-        if all(len(drafts[r.rid]) == 0 for r in ready):
+        tr = self.tracer
+        with tr.span("spec.prepare", **self._planned(reqs)):
+            ready = self._ready_rows(step, reqs, lookahead)
             # nothing to verify: a chunk-wide forward would commit the
             # same single token per row at prefill-shaped cost — take
             # the (B, 1) decode path (capacity/CoW above already cover
             # one token, so the re-check inside is a no-op)
+            plain = all(len(drafts[r.rid]) == 0 for r in ready)
+            if ready and not plain:
+                bucket = min(self._bucket(len(ready)), self.ecfg.max_batch)
+                c = self._spec_k + 1
+                tokens = np.zeros((bucket, c), np.int32)
+                draft = np.zeros((bucket, c - 1), np.int32)
+                n_valid = np.zeros(bucket, np.int32)
+                lengths = np.zeros(bucket, np.int32)
+                for i, r in enumerate(ready):
+                    d = drafts[r.rid]
+                    tokens[i, 0] = r.last_token
+                    tokens[i, 1:1 + len(d)] = d
+                    draft[i, :len(d)] = d
+                    n_valid[i] = len(d) + 1
+                    lengths[i] = r.pos
+                table = self.cache.table_rows(ready, bucket)
+                slots = self.cache.slot_rows(ready, bucket)
+                srows = sampling_rows(ready, bucket)
+                j_tokens, j_table, j_lengths, j_valid, j_slots = (
+                    jnp.asarray(tokens), jnp.asarray(table),
+                    jnp.asarray(lengths), jnp.asarray(n_valid),
+                    jnp.asarray(slots))
+                j_draft = jnp.asarray(draft)
+        if not ready:
+            return
+        if plain:
             self._run_decode(step, ready)
             return
-        bucket = min(self._bucket(len(ready)), self.ecfg.max_batch)
-        c = self._spec_k + 1
-        tokens = np.zeros((bucket, c), np.int32)
-        draft = np.zeros((bucket, c - 1), np.int32)
-        n_valid = np.zeros(bucket, np.int32)
-        lengths = np.zeros(bucket, np.int32)
-        for i, r in enumerate(ready):
-            d = drafts[r.rid]
-            tokens[i, 0] = r.last_token
-            tokens[i, 1:1 + len(d)] = d
-            draft[i, :len(d)] = d
-            n_valid[i] = len(d) + 1
-            lengths[i] = r.pos
-        table = self.cache.table_rows(ready, bucket)
-        slots = self.cache.slot_rows(ready, bucket)
-        srows = sampling_rows(ready, bucket)
-        j_tokens, j_table, j_lengths, j_valid, j_slots = (
-            jnp.asarray(tokens), jnp.asarray(table), jnp.asarray(lengths),
-            jnp.asarray(n_valid), jnp.asarray(slots))
-        sampled, n_commit, pools, snaps = self._spec_fn(
-            self.params, self.cache.pools, j_tokens, j_table, j_lengths,
-            j_valid, j_slots, jnp.asarray(draft), *srows.as_args())
-        self.cache.pools = pools
+        fed = int(n_valid[:len(ready)].sum())
+        stats = {"rows": len(ready), "bucket": bucket, "tokens": fed,
+                 "chunk": bucket * c}
+        with tr.span("spec.dispatch", **stats):
+            sampled, n_commit_d, pools, snaps = self._spec_fn(
+                self.params, self.cache.pools, j_tokens, j_table,
+                j_lengths, j_valid, j_slots, j_draft, *srows.as_args())
+            self.cache.pools = pools
+        with tr.span("spec.sync", **stats):
+            n_commit = np.asarray(n_commit_d)
+            sampled = np.asarray(sampled)
         # recurrent slots folded the FULL draft into their state; any
         # partial acceptance needs the snapshot-restore + re-advance
         if self._has_slots and bool(np.any(
-                np.asarray(n_commit)[:len(ready)] < n_valid[:len(ready)])):
-            self.cache.pools = self._repair_fn(
-                self.params, self.cache.pools, j_tokens, j_table,
-                j_lengths, n_commit, j_slots, snaps)
+                n_commit[:len(ready)] < n_valid[:len(ready)])):
+            with tr.span("spec.dispatch", **stats):
+                self.cache.pools = self._repair_fn(
+                    self.params, self.cache.pools, j_tokens, j_table,
+                    j_lengths, n_commit_d, j_slots, snaps)
             self._spec_repairs += 1
-        sampled = np.asarray(sampled)
-        n_commit = np.asarray(n_commit)
+        with tr.span("spec.commit", **stats):
+            self._commit_spec(step, ready, sampled, n_commit, n_valid,
+                              bucket)
+
+    def _commit_spec(self, step: int, ready: list[Request],
+                     sampled: np.ndarray, n_commit: np.ndarray,
+                     n_valid: np.ndarray, bucket: int):
+        """Commit each row's accepted draft prefix plus the verifier's
+        own token, and account the verify step."""
         self._max_concurrent = max(self._max_concurrent, len(ready))
         self._decode_calls += 1
         self._decode_rows += len(ready)

@@ -91,6 +91,7 @@ class Request:
     first_token_step: int | None = None
     finish_step: int | None = None
     submit_s: float | None = None
+    admit_s: float | None = None       # stamped with admit_step
     first_token_s: float | None = None
     finish_s: float | None = None
 

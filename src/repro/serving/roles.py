@@ -198,13 +198,14 @@ def build_step_fns(cfg, ecfg, role: Role, *, ring: bool,
                                         ring=ring_, attn_impl=attn_impl_)
         # chunk-final logits row -> the would-be next token (used by
         # the engine only when this chunk completes the prompt)
-        gather = jnp.maximum(n_valid - 1, 0)[:, None, None]
-        last = jnp.take_along_axis(
-            logits, jnp.broadcast_to(
-                gather, (logits.shape[0], 1, logits.shape[2])),
-            axis=1)[:, 0]
-        tok = sample_tokens(last, lengths + n_valid,
-                            seeds, temps, top_k, top_p)
+        with jax.named_scope("sample"):
+            gather = jnp.maximum(n_valid - 1, 0)[:, None, None]
+            last = jnp.take_along_axis(
+                logits, jnp.broadcast_to(
+                    gather, (logits.shape[0], 1, logits.shape[2])),
+                axis=1)[:, 0]
+            tok = sample_tokens(last, lengths + n_valid,
+                                seeds, temps, top_k, top_p)
         return tok, logits, pools
 
     prefill_fn = jax.jit(_pin_bnn(_prefill), donate_argnums=(1,))
@@ -217,8 +218,9 @@ def build_step_fns(cfg, ecfg, role: Role, *, ring: bool,
                                             table, lengths, active,
                                             slots, ring=ring_,
                                             attn_impl=attn_impl_)
-        tok = sample_tokens(logits[:, -1], lengths + 1,
-                            seeds, temps, top_k, top_p)
+        with jax.named_scope("sample"):
+            tok = sample_tokens(logits[:, -1], lengths + 1,
+                                seeds, temps, top_k, top_p)
         return tok, logits, pools
 
     decode_fn = jax.jit(_pin_bnn(_decode), donate_argnums=(1,))
@@ -236,10 +238,11 @@ def build_step_fns(cfg, ecfg, role: Role, *, ring: bool,
         idx = (lengths[:, None] + 1
                + jnp.arange(c, dtype=jnp.int32)[None, :])
         rep = lambda a: jnp.repeat(a, c)
-        sampled = sample_tokens(
-            logits.reshape(b * c, -1), idx.reshape(-1),
-            rep(seeds), rep(temps), rep(top_k), rep(top_p)
-        ).reshape(b, c)
+        with jax.named_scope("sample"):
+            sampled = sample_tokens(
+                logits.reshape(b * c, -1), idx.reshape(-1),
+                rep(seeds), rep(temps), rep(top_k), rep(top_p)
+            ).reshape(b, c)
         # accepted draft prefix: position j counts while the verifier's
         # token agrees with the draft's
         j = jnp.arange(c - 1, dtype=jnp.int32)[None, :]

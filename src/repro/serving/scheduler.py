@@ -29,6 +29,7 @@ Every action appends a trace event — tests assert continuous batching
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 
 from repro.serving import roles as R
@@ -204,6 +205,7 @@ class Scheduler:
                 break
             req.state = State.PREFILL
             req.admit_step = step
+            req.admit_s = time.perf_counter()
             self.queue.remove(req)
             self.running.append(req)
             plan.admitted.append(req)

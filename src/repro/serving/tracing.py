@@ -15,12 +15,22 @@ bounded in-memory ring and (optionally) a JSONL file:
                   first_token -> finish, plus defer/evict/swap_out/
                   swap_in/swap_lost with their reasons), forwarded from
                   the scheduler's event stream;
-  * ``span``    — a timed host-side operation (swap/snapshot copies).
-                  The span API is ALSO the single source of truth for
-                  the engine's wall-time accounting: ``span_total``
-                  backs ``stats()`` whether or not tracing is enabled,
-                  so the stats totals always equal the sum of the
-                  emitted span records.
+  * ``span``    — a timed host-side scope: the phases of a step
+                  (``schedule``, ``<kind>.prepare``, ``<kind>.dispatch``,
+                  ``<kind>.sync``, ``<kind>.commit`` for kind prefill /
+                  decode / spec) and the swap/snapshot/handoff copies,
+                  each with the ``step`` it ran in and the ``parent``
+                  span that encloses it.  The span API is ALSO the
+                  single source of truth for the engine's wall-time
+                  accounting: ``span_total`` backs ``stats()`` whether
+                  or not tracing is enabled, so the stats totals always
+                  equal the sum of the emitted records.
+
+Every span also opens a ``jax.profiler.TraceAnnotation`` named
+``engine.<name>`` with the span's stats (``step`` and the span's own
+fields), enabled or not: under a profiler session the engine's phases
+sit in the same trace as the device's operations, on one clock, and
+join the JSONL records on ``step``.
 
 The first line of every trace is a ``meta`` record carrying the schema
 version, the full arch config (a flat dataclass — ``replay.load_config``
@@ -31,15 +41,17 @@ nothing but the JSONL.
 Disabled-path contract (the default): ``tracer.enabled`` is False, the
 engine's hot path skips building event dicts entirely (guarded by
 ``if tracer.enabled``), ``emit`` returns before touching the ring, and
-spans only do the two ``perf_counter`` calls plus one float add the old
-ad-hoc accumulators already did.  tests/test_tracing.py pins this with
-an allocation guard.
+a span costs two ``perf_counter`` calls, one float add and one
+``TraceAnnotation`` (which records nothing outside a profiler
+session).  tests/test_tracing.py pins this with an allocation guard.
 """
 from __future__ import annotations
 
 import json
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
 
 # v2: meta carries ``shard``/``n_shards`` and step records carry a
 # ``shard`` field when the engine runs as one shard of a ShardedEngine
@@ -55,17 +67,24 @@ from collections import deque
 # terminal ``cancelled`` request event (never counted as swap_lost),
 # the ``tenant_budget`` defer reason joins the stall vocabulary, and
 # scoring prefills mark their step-record prefill info ``score=True``.
-TRACE_SCHEMA_VERSION = 4
+# v5: the phases of every step are ``span`` records
+# (``schedule``, ``prefill.prepare`` ... ``decode.commit``), and every
+# span record carries ``step`` and ``parent`` (the enclosing span's
+# name, null at top level); the root ``step`` span is the step record.
+TRACE_SCHEMA_VERSION = 5
 
 # record types a valid trace may contain (schema checks + exporter)
 RECORD_TYPES = ("meta", "step", "request", "span")
 
 
 class _Span:
-    """Timed scope: accumulates into ``tracer.span_totals[name]`` and
-    (when tracing is on) emits one ``span`` record on exit."""
+    """Timed scope: accumulates into ``tracer.span_totals[name]``, opens
+    the profiler annotation ``engine.<name>``, and (when tracing is on)
+    emits one ``span`` record on exit.  ``dur`` holds the scope's
+    seconds once it has closed."""
 
-    __slots__ = ("tracer", "name", "rid", "extra", "t0")
+    __slots__ = ("tracer", "name", "rid", "extra", "t0", "dur", "parent",
+                 "_note")
 
     def __init__(self, tracer: "Tracer", name: str, rid, extra):
         self.tracer = tracer
@@ -74,16 +93,28 @@ class _Span:
         self.extra = extra
 
     def __enter__(self):
+        tr = self.tracer
+        self.parent = tr.open_span
+        tr.open_span = self
+        stats = self.extra if self.rid is None else \
+            {"rid": self.rid, **self.extra}
+        self._note = TraceAnnotation("engine." + self.name, step=tr.step,
+                                     **stats)
+        self._note.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        dur = time.perf_counter() - self.t0
+        self.dur = dur = time.perf_counter() - self.t0
+        self._note.__exit__(*exc)
         tr = self.tracer
+        tr.open_span = self.parent
         tr.add_time(self.name, dur)
-        if tr.enabled:
+        if tr.enabled and self.name != "step":
+            parent = self.parent
             rec = {"type": "span", "name": self.name, "ts": self.t0 - tr.t0,
-                   "dur_s": dur}
+                   "dur_s": dur, "step": tr.step,
+                   "parent": parent.name if parent is not None else None}
             if self.rid is not None:
                 rec["rid"] = self.rid
             if self.extra:
@@ -102,7 +133,7 @@ class Tracer:
     """
 
     __slots__ = ("enabled", "capture_logits", "ring", "t0", "span_totals",
-                 "span_counts", "_fh", "_path")
+                 "step", "open_span", "_fh", "_path")
 
     def __init__(self):
         self.enabled = False
@@ -110,7 +141,8 @@ class Tracer:
         self.ring: deque | None = None
         self.t0 = time.perf_counter()
         self.span_totals: dict[str, float] = {}
-        self.span_counts: dict[str, int] = {}
+        self.step = 0                # the owner's step count, for spans
+        self.open_span: _Span | None = None
         self._fh = None
         self._path = None
 
@@ -174,13 +206,14 @@ class Tracer:
     # -------------------------------------------------------------- spans
 
     def span(self, name: str, rid=None, **extra) -> _Span:
-        """Timed scope; accumulates into ``span_totals`` always, emits a
-        ``span`` record only while tracing is enabled."""
+        """Timed scope; accumulates into ``span_totals`` and annotates
+        the profiler's trace always, emits a ``span`` record only while
+        tracing is enabled.  ``rid`` and ``extra`` are the span's stats
+        in both."""
         return _Span(self, name, rid, extra)
 
     def add_time(self, name: str, dur_s: float):
         self.span_totals[name] = self.span_totals.get(name, 0.0) + dur_s
-        self.span_counts[name] = self.span_counts.get(name, 0) + 1
 
     def span_total(self, name: str) -> float:
         return self.span_totals.get(name, 0.0)
@@ -190,11 +223,9 @@ class Tracer:
         the tracer-side half of ``reset_stats``."""
         if not names:
             self.span_totals.clear()
-            self.span_counts.clear()
             return
         for n in names:
             self.span_totals.pop(n, None)
-            self.span_counts.pop(n, None)
 
 
 def read_trace(path) -> list[dict]:
@@ -222,7 +253,7 @@ def validate_trace(records: list[dict]):
                          f"supported {TRACE_SCHEMA_VERSION}")
     required = {"step": ("step", "dur_s"),
                 "request": ("event", "rid"),
-                "span": ("name", "dur_s"),
+                "span": ("name", "dur_s", "step", "parent"),
                 "meta": ("schema",)}
     for i, rec in enumerate(records):
         t = rec.get("type")

@@ -287,7 +287,7 @@ def test_handoff_spans_and_replay_transfer_term(bnn_cfg, bnn_params,
     for i, records in all_records.items():
         validate_trace(records)
         meta = records[0]
-        assert meta["schema"] == 4
+        assert meta["schema"] == 5
         assert meta["role"] == se.roles[i]
         assert meta["link_gbps"] == 100.0
         assert "t0" in meta

@@ -335,12 +335,12 @@ def test_trace_schema_per_shard_fields(bnn_cfg, bnn_params, tmp_path):
     rids = [se.submit(p, 6) for p in _prompts(bnn_cfg, [4, 4], seed=19)]
     se.run()
     se.stop_trace()
-    assert TRACE_SCHEMA_VERSION == 4
+    assert TRACE_SCHEMA_VERSION == 5
     for i in range(2):
         records = read_trace(f"{prefix}.shard{i}.jsonl")
         validate_trace(records)
         meta = records[0]
-        assert meta["schema"] == 4
+        assert meta["schema"] == 5
         assert meta["shard"] == i and meta["n_shards"] == 2
         # v3: worker role + clock anchor in meta, role on every step
         assert meta["role"] == "mixed" and "t0" in meta

@@ -133,3 +133,77 @@ def test_shard_meshes_one_chip_per_shard(topo):
     assert [m.devices.flat[0] for m in meshes] == list(topo.devices)
     with pytest.raises(ValueError):
         S.shard_meshes(5, devices=topo.devices)
+
+
+# the serving step programs at qwen1.5-0.5b widths, one layer deep: the
+# names a profiler trace of the chip attributes device time by
+KERNEL_NAMES = ("paged_attention", "bnn_xnor_gemm", "bnn_binarize_pack")
+SCOPES = ("attn", "kv_update", "ffn", "head", "sample", "weight_pack")
+
+
+@pytest.fixture(scope="module")
+def step_programs(topo, compile_tpu, request):
+    """Compiled text of the engine's decode (bucket 8) and prefill (chunk
+    64) programs for one v5e chip, with the Pallas kernels selected as
+    on the chip (the platform is reported as "tpu" while tracing)."""
+    import numpy as np
+
+    from repro import configs
+    from repro.models import transformer as M
+    from repro.serving import EngineConfig
+    from repro.serving import roles as R
+
+    mp = pytest.MonkeyPatch()
+    request.addfinalizer(mp.undo)
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+    dev = SingleDeviceSharding(topo.devices[0])
+    cfg = configs.get_config("qwen1.5-0.5b").replace(n_layers=1,
+                                                     precision="bnn")
+    ecfg = EngineConfig(block_size=16, num_blocks=65, max_batch=8,
+                        prefill_chunk=64, max_model_len=256)
+    fns = R.build_step_fns(cfg, ecfg, R.get_role("mixed"), ring=False,
+                           spec_k=0)
+
+    def spec(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=dev), tree)
+
+    params = spec(M.abstract_init(cfg)[0])
+    pools = spec(jax.eval_shape(lambda: M.init_paged_state(
+        cfg, ecfg.num_blocks, ecfg.block_size)))
+    mb = ecfg.max_model_len // ecfg.block_size
+
+    def rows(b):
+        return spec([np.zeros(b, np.uint32), np.zeros(b, np.float32),
+                     np.zeros(b, np.int32), np.ones(b, np.float32)])
+
+    def i32(*shape):
+        return spec(np.zeros(shape, np.int32))
+
+    text = {}
+    text["decode"] = fns.decode.lower(
+        params, pools, i32(8, 1), i32(8, mb), i32(8), spec(np.zeros(8, bool)),
+        i32(8), *rows(8)).compile().as_text()
+    text["prefill"] = fns.prefill.lower(
+        params, pools, i32(1, ecfg.prefill_chunk), i32(1, mb), i32(1),
+        i32(1), i32(1), *rows(1)).compile().as_text()
+    return text
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_step_programs_carry_kernel_names_and_scopes(step_programs,
+                                                     program):
+    text = step_programs[program]
+    # every Pallas kernel is a tpu_custom_call instruction named after it
+    calls = [line.split("=", 1)[0].split()[-1].lstrip("%")
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in KERNEL_NAMES:
+        assert any(c.startswith(name) for c in calls), (name, calls)
+    op_names = set()
+    for line in text.splitlines():
+        if 'op_name="' in line:
+            op_names.update(line.split('op_name="', 1)[1].split('"', 1)[0]
+                            .split("/"))
+    for scope in SCOPES:
+        assert scope in op_names, scope

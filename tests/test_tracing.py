@@ -104,7 +104,9 @@ def test_disabled_tracing_is_inert(bnn_cfg, bnn_params, monkeypatch):
 
 def test_tracing_off_matches_on_token_for_token(bnn_cfg, bnn_params,
                                                 tmp_path):
-    """Observability never changes results: same tokens traced or not."""
+    """Observability never changes results: same tokens traced or not,
+    and under a profiler session or not."""
+    import jax
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, bnn_cfg.vocab, 5) for _ in range(3)]
     plain = _engine(bnn_cfg, bnn_params)
@@ -117,6 +119,156 @@ def test_tracing_off_matches_on_token_for_token(bnn_cfg, bnn_params,
     traced.stop_trace()
     for ra, rb in zip(rids, rids_t):
         np.testing.assert_array_equal(out_plain[ra], out_traced[rb])
+    profiled = _engine(bnn_cfg, bnn_params)
+    rids_p = [profiled.submit(p, 6) for p in prompts]
+    jax.profiler.start_trace(str(tmp_path / "xplane"))
+    try:
+        out_profiled = profiled.run()
+    finally:
+        jax.profiler.stop_trace()
+    for ra, rb in zip(rids, rids_p):
+        np.testing.assert_array_equal(out_plain[ra], out_profiled[rb])
+
+
+# ------------------------------------------------ spans in the profiler
+
+PHASES = ("prepare", "dispatch", "sync", "commit")
+
+
+def _engine_events(trace_dir) -> list[tuple[str, int, int, dict]]:
+    """(span name, start ns, end ns, stats) of every ``engine.*`` host
+    event in the newest xplane under ``trace_dir``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                      "*", "*.xplane.pb")),
+               key=os.path.getmtime)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("engine."):
+                    out.append((ev.name[len("engine."):], ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                {k: int(v) for k, v in ev.stats}))
+    return out
+
+
+def test_profiler_trace_holds_engine_phase_spans(bnn_cfg, bnn_params,
+                                                 tmp_path):
+    """Under a profiler session every step is an ``engine.step`` host
+    event whose phases (schedule, then prepare / dispatch / sync /
+    commit of each call) lie inside it, all stamped with its step."""
+    import jax
+    eng = _engine(bnn_cfg, bnn_params)
+    rng = np.random.default_rng(4)
+    eng.submit(rng.integers(0, bnn_cfg.vocab, 4), 3)
+    eng.run()                          # compiles outside the trace
+    for i in range(3):
+        eng.submit(rng.integers(0, bnn_cfg.vocab, 4), 3 + i)
+    first = eng.step_count
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    evs = _engine_events(tmp_path)
+    roots = [e for e in evs if e[0] == "step"]
+    assert [e[3]["step"] for e in roots] == list(range(first,
+                                                       eng.step_count))
+    assert all("step" in e[3] for e in evs)
+    for name, s0, s1, st in roots:
+        kids = [e for e in evs if e[0] != "step" and e[3]["step"]
+                == st["step"]]
+        assert all(s0 <= k[1] <= k[2] <= s1 for k in kids)
+        names = {k[0] for k in kids}
+        assert "schedule" in names
+        for phase in PHASES:
+            assert any(n.endswith("." + phase) for n in names), \
+                (st["step"], phase, names)
+    for name, _, _, st in evs:
+        if name.startswith("decode."):
+            assert st["bucket"] >= st["rows"] >= 1
+        if name.startswith("prefill."):
+            assert {"rid", "tokens", "chunk"} <= set(st)
+            assert st["chunk"] == eng.ecfg.prefill_chunk >= st["tokens"]
+
+
+def test_disabled_tracing_still_times_every_phase(bnn_cfg, bnn_params,
+                                                  monkeypatch):
+    """Tracing off: the phase spans accumulate their totals (and annotate
+    the profiler) but never reach ``emit``."""
+    def boom(self, record):
+        raise AssertionError(f"emit() called while disabled: {record}")
+    monkeypatch.setattr(Tracer, "emit", boom)
+    eng = _engine(bnn_cfg, bnn_params)
+    eng.submit(np.arange(4, dtype=np.int32), 4)
+    eng.run()
+    tr = eng.tracer
+    assert tr.open_span is None and tr.step == eng.step_count
+    for name in ("step", "schedule", "prefill.prepare", "prefill.dispatch",
+                 "prefill.sync", "prefill.commit", "decode.prepare",
+                 "decode.dispatch", "decode.sync", "decode.commit"):
+        assert tr.span_total(name) > 0, name
+    assert not hasattr(tr, "span_counts")
+
+
+def test_phase_span_totals_equal_trace_span_sums(bnn_cfg, bnn_params,
+                                                 tmp_path):
+    """Every span name's accumulated total is the sum of its emitted
+    records; the root ``step`` span is the step records' sum."""
+    eng, path = _traced_run(bnn_cfg, bnn_params, tmp_path)
+    records = read_trace(path)
+    by_name: dict[str, float] = {}
+    for r in records:
+        if r["type"] == "span":
+            by_name[r["name"]] = by_name.get(r["name"], 0.0) + r["dur_s"]
+    assert "step" not in by_name
+    assert {"schedule", "decode.sync", "prefill.commit"} <= set(by_name)
+    for name, total in by_name.items():
+        assert np.isclose(eng.tracer.span_total(name), total,
+                          rtol=1e-9), name
+    steps = [r for r in records if r["type"] == "step"]
+    assert np.isclose(eng.tracer.span_total("step"),
+                      sum(r["dur_s"] for r in steps), rtol=1e-9)
+
+
+def test_span_records_carry_step_and_parent(bnn_cfg, bnn_params,
+                                            tmp_path):
+    """Schema v5: every span record names its step and the span that
+    encloses it; a step's phases are children of the root ``step`` and
+    copies are children of the phase they ran in."""
+    eng = _engine(bnn_cfg, bnn_params, block_size=2, num_blocks=9,
+                  max_batch=2, max_model_len=12, prefill_chunk=4,
+                  preempt_policy="swap")
+    path = str(tmp_path / "trace.jsonl")
+    eng.start_trace(path)
+    rng = np.random.default_rng(1)
+    for _ in range(2):
+        eng.submit(rng.integers(0, bnn_cfg.vocab, 4), 8)
+    eng.run()
+    eng.stop_trace()
+    records = read_trace(path)
+    assert records[0]["schema"] == TRACE_SCHEMA_VERSION == 5
+    steps = {r["step"] for r in records if r["type"] == "step"}
+    spans = [r for r in records if r["type"] == "span"]
+    assert spans and all(r["step"] in steps for r in spans)
+    for r in spans:
+        if r["name"] == "schedule" or r["name"].rsplit(".", 1)[-1] \
+                in PHASES:
+            assert r["parent"] == "step", r
+    copies = [r for r in spans if r["name"].startswith(("swap_",
+                                                        "snapshot_"))]
+    assert copies, "forced preemption must emit swap spans"
+    assert all(r["parent"] == "schedule"
+               or r["parent"].endswith(".prepare") for r in copies)
+    with pytest.raises(ValueError, match="missing field 'parent'"):
+        validate_trace([records[0], {"type": "span", "name": "x",
+                                     "dur_s": 0.0, "step": 0}])
 
 
 # ------------------------------------------------- stats == span sums
@@ -248,6 +400,20 @@ def test_perfetto_export_track_structure(bnn_cfg, bnn_params, tmp_path):
     # step slices are named by kind and carry the step payload
     step_names = {e["name"] for e in slices if e["pid"] == 1}
     assert any("decode" in n for n in step_names)
+    # the phases of each step get a track of their own, apart from the
+    # copies, one slice per phase span record with its step and parent
+    assert (1, "phases") in names
+    phases = [e for e in slices if e["pid"] == 1 and e["tid"] == 3]
+    n_phase = len([r for r in records if r["type"] == "span"
+                   and r["name"] != "swap_out" and r["name"] != "swap_in"
+                   and not r["name"].startswith(("snapshot_", "handoff_"))])
+    assert len(phases) == n_phase > 0
+    assert {"schedule", "decode.dispatch", "decode.sync"} <= \
+        {e["name"] for e in phases}
+    assert all(e["args"]["parent"] == "step" and "step" in e["args"]
+               for e in phases)
+    assert not [e for e in slices if e["pid"] == 1 and e["tid"] == 2
+                and e["name"] in {p["name"] for p in phases}]
 
 
 # ------------------------------------------------------ bench schema
