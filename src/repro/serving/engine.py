@@ -489,7 +489,7 @@ class Engine:
                     jnp.asarray([req.pos], jnp.int32),
                     jnp.asarray([chunk], jnp.int32), jnp.asarray(slots),
                     *srows.as_args())
-        with tr.span("prefill.dispatch", **stats):
+        with tr.span("prefill.dispatch", sampled=srows.sampled, **stats):
             tok, _logits, pools = self._prefill_fn(
                 self.params, self.cache.pools, *args)
             self.cache.pools = pools
@@ -625,7 +625,7 @@ class Engine:
                     jnp.asarray(lengths), jnp.asarray(active),
                     jnp.asarray(slots), *srows.as_args())
         stats = {"rows": len(ready), "bucket": bucket}
-        with tr.span("decode.dispatch", **stats):
+        with tr.span("decode.dispatch", sampled=srows.sampled, **stats):
             next_tok, _dec_logits, pools = self._decode_fn(
                 self.params, self.cache.pools, *args)
             self.cache.pools = pools
@@ -712,7 +712,7 @@ class Engine:
         fed = int(n_valid[:len(ready)].sum())
         stats = {"rows": len(ready), "bucket": bucket, "tokens": fed,
                  "chunk": bucket * c}
-        with tr.span("spec.dispatch", **stats):
+        with tr.span("spec.dispatch", sampled=srows.sampled, **stats):
             sampled, n_commit_d, pools, snaps = self._spec_fn(
                 self.params, self.cache.pools, j_tokens, j_table,
                 j_lengths, j_valid, j_slots, j_draft, *srows.as_args())

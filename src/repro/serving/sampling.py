@@ -17,7 +17,13 @@ Sampling itself is Gumbel-max over the filtered logits: top-k keeps the
 k highest logits, top-p keeps the smallest prefix of the sorted
 distribution whose probability mass reaches p (always at least the top
 token), and ``argmax(logits/T + gumbel)`` draws exactly from the
-renormalized categorical — no explicit normalization needed.
+renormalized categorical — no explicit normalization needed.  The
+filter sorts the values only: the kept prefix ends at some value, so
+the support is masked in vocabulary order as ``logits >= cut`` — no
+argsort and no scatter back (a TPU expands that scatter into an index
+sort and flattened fusions over every row's vocabulary).  Every token
+tied with the cut is kept, and ``top_p = 1`` keeps the whole
+vocabulary.
 """
 from __future__ import annotations
 
@@ -66,6 +72,11 @@ class SamplingRows:
     top_k: np.ndarray             # (B,) int32
     top_p: np.ndarray             # (B,) float32
 
+    @property
+    def sampled(self) -> int:
+        """Rows drawn by the filter and Gumbel noise (temperature > 0)."""
+        return int(np.count_nonzero(self.temps > 0))
+
     def as_args(self):
         return (jnp.asarray(self.seeds), jnp.asarray(self.temps),
                 jnp.asarray(self.top_k), jnp.asarray(self.top_p))
@@ -88,10 +99,20 @@ def sampling_rows(reqs, batch: int) -> SamplingRows:
 
 
 def _filter_row(logits: Array, top_k: Array, top_p: Array) -> Array:
-    """Mask one row's logits to the top-k / nucleus support (-inf out)."""
+    """Mask one row's logits to the top-k / nucleus support (NEG out).
+
+    The support is a prefix of the descending sorted values, so it is
+    every token whose logit reaches the value at the prefix's last rank:
+    the cut is found on a values-only sort and applied in vocabulary
+    order, with no permutation back.  Ties: every token equal to the cut
+    is kept, so the support depends on the logit values alone.
+    ``top_p >= 1`` keeps every token (a float32 cumsum saturates below 1
+    in a wide row's tail; the test would otherwise drop it).
+    """
     v = logits.shape[-1]
-    order = jnp.argsort(-logits)                      # descending
-    ranked = logits[order]                            # sorted values
+    # descending values; unstable, as equal values need no order (a
+    # stable sort carries an index operand through it on a TPU)
+    ranked = -jnp.sort(-logits, stable=False)
     # top-k: rank >= k is out (k == 0 disables)
     ranks = jnp.arange(v, dtype=jnp.int32)
     keep = (top_k <= 0) | (ranks < top_k)
@@ -99,10 +120,13 @@ def _filter_row(logits: Array, top_k: Array, top_p: Array) -> Array:
     # "- prob" keeps every token whose cumsum FIRST reaches p (so the
     # top token always survives even when p < its probability)
     probs = jax.nn.softmax(ranked)
-    keep &= (jnp.cumsum(probs) - probs < top_p)
-    masked = jnp.where(keep, ranked, NEG)
-    # scatter the mask back to vocab order
-    return jnp.zeros(v, logits.dtype).at[order].set(masked)
+    keep &= (top_p >= 1.0) | (jnp.cumsum(probs) - probs < top_p)
+    # n = leading ranks that pass (the first failing rank; rank 0 always
+    # passes, so n >= 1): a float32 cumsum need not be monotone, so a
+    # count of the passing ranks could reach past the first that fails
+    fail = ~keep
+    n = jnp.where(jnp.any(fail), jnp.argmax(fail), v)
+    return jnp.where(logits >= ranked[n - 1], logits, NEG)
 
 
 def _sample_row(logits: Array, key: Array, temp: Array, top_k: Array,

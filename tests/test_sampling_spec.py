@@ -15,13 +15,14 @@ Contracts under test:
     reproduces sampled non-spec decoding, and partial draft acceptance
     rolls back correctly on SSM slots and ring tables.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.serving import SamplingParams, nearest_rank, prompt_lookup_draft
 from repro.serving.request import State
-from repro.serving.sampling import sample_tokens
+from repro.serving.sampling import NEG, _filter_row, sample_tokens, token_key
 from test_serving import _engine  # bnn_cfg/bnn_params live in conftest.py
 
 
@@ -88,6 +89,146 @@ def test_sample_tokens_greedy_and_filters():
     np.testing.assert_array_equal(a, b)
     draws = {tuple(_sample(logits, i, seed=3, temp=5.0)) for i in range(32)}
     assert len(draws) > 1
+
+
+_filter = jax.jit(jax.vmap(_filter_row))
+
+
+def _logit_rows(seed, n, width):
+    """Random logit rows, each with its own spread: nuclei from one
+    token to thousands, as a vocabulary head at various temperatures
+    gives."""
+    rng = np.random.default_rng(seed)
+    spread = np.geomspace(0.5, 24.0, n)[:, None]
+    return (rng.normal(size=(n, width)) * spread).astype(np.float32)
+
+
+def _reference_support(row, top_k, top_p, tol=4e-6):
+    """float64 NumPy reference of the filter: sort, softmax, cumsum, the
+    prefix cut.  Returns (kept mask in vocabulary order, decided):
+    ``decided`` is False where the cut is tied or where the mass ahead
+    of a rank up to the cut lies within ``tol`` of p, so that float32
+    rounding, not the algorithm, places the cut (the float32 cumsum of
+    a 151936-wide row strays from float64 by under 4e-7)."""
+    x = row.astype(np.float64)
+    v = x.size
+    order = np.argsort(-x, kind="stable")
+    ranked = x[order]
+    n = v if top_k <= 0 else min(top_k, v)
+    decided = True
+    if top_p < 1.0:
+        p = np.exp(ranked - ranked[0])
+        p /= p.sum()
+        ahead = np.concatenate([[0.0], np.cumsum(p)[:-1]])
+        out = np.nonzero(ahead >= top_p)[0]
+        n = min(n, int(out[0]) if out.size else v)
+        near = np.abs(ahead[1:n + 1] - top_p) < tol
+        decided = not near.any()
+    if n < v and ranked[n] == ranked[n - 1]:
+        decided = False
+    keep = np.zeros(v, bool)
+    keep[order[:n]] = True
+    return keep, decided
+
+
+@pytest.mark.parametrize("width", [64, 151936])
+@pytest.mark.parametrize("top_p", [1e-6, 0.5, 0.9, 1.0])
+@pytest.mark.parametrize("top_k", [0, 1, 8, 1000])
+def test_filter_support_matches_float64_reference(top_k, top_p, width):
+    """The threshold mask keeps exactly the reference's prefix wherever
+    the cut is decided, and keeps each logit unchanged."""
+    logits = _logit_rows(width, 12, width)
+    out = np.asarray(_filter(jnp.asarray(logits),
+                             jnp.full(12, top_k, jnp.int32),
+                             jnp.full(12, top_p, jnp.float32)))
+    kept = out > NEG
+    np.testing.assert_array_equal(out[kept], logits[kept])
+    assert np.all(out[~kept] == NEG)
+    assert kept.any(axis=1).all()             # the top token survives
+    decided = 0
+    for i in range(12):
+        want, ok = _reference_support(logits[i], top_k, top_p)
+        if ok:
+            decided += 1
+            np.testing.assert_array_equal(kept[i], want, err_msg=str(i))
+    assert decided >= 4
+
+
+def test_filter_top_p_one_keeps_whole_vocabulary():
+    """top_p = 1 keeps every token of a 151936-wide row, although the
+    float32 cumsum saturates below 1 in the tail."""
+    logits = _logit_rows(1, 8, 151936)
+    out = np.asarray(_filter(jnp.asarray(logits), jnp.zeros(8, jnp.int32),
+                             jnp.ones(8, jnp.float32)))
+    np.testing.assert_array_equal(out, logits)
+
+
+def test_filter_keeps_every_token_tied_at_cut():
+    """Tokens equal to the cut's value are all kept, for a top-k cut and
+    for a nucleus cut."""
+    row = np.full(64, -30.0, np.float32)
+    row[[5, 17, 40, 63]] = [4.0, 2.0, 2.0, 2.0]
+    row[9] = -1.0
+    rows = jnp.asarray(np.stack([row, row]))
+    # top-k 2 cuts at rank 1; mass ahead of rank 2 is ~0.71 + 0.10,
+    # past a top-p of 0.8, so the nucleus too cuts after one tied token
+    out = np.asarray(_filter(rows, jnp.asarray([2, 0], jnp.int32),
+                             jnp.asarray([1.0, 0.8], jnp.float32)))
+    for r in out:
+        np.testing.assert_array_equal(np.nonzero(r > NEG)[0],
+                                      [5, 17, 40, 63])
+
+
+def _scatter_filter_row(logits, top_k, top_p):
+    """The argsort-and-scatter filter the threshold mask replaced."""
+    v = logits.shape[-1]
+    order = jnp.argsort(-logits)
+    ranked = logits[order]
+    ranks = jnp.arange(v, dtype=jnp.int32)
+    keep = (top_k <= 0) | (ranks < top_k)
+    probs = jax.nn.softmax(ranked)
+    keep &= (jnp.cumsum(probs) - probs < top_p)
+    masked = jnp.where(keep, ranked, NEG)
+    return jnp.zeros(v, logits.dtype).at[order].set(masked)
+
+
+@jax.jit
+def _scatter_sample_tokens(logits, index, seeds, temps, top_k, top_p):
+    def row(lg, key, temp, k, p):
+        greedy_tok = jnp.argmax(lg).astype(jnp.int32)
+        filtered = _scatter_filter_row(
+            lg.astype(jnp.float32) / jnp.maximum(temp, 1e-6), k, p)
+        gumbel = jax.random.gumbel(key, lg.shape, jnp.float32)
+        sampled = jnp.argmax(filtered + gumbel).astype(jnp.int32)
+        return jnp.where(temp > 0, sampled, greedy_tok)
+    keys = jax.vmap(token_key)(seeds, index)
+    return jax.vmap(row)(logits, keys, temps, top_k, top_p)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_sampled_batch_matches_argsort_scatter_filter(seed):
+    """A decode batch of 32 at the 151936 vocabulary, greedy rows mixed
+    with top-p and top-k rows: the same tokens as the argsort-and-scatter
+    filter, for data whose cuts are untied."""
+    b, v = 32, 151936
+    rng = np.random.default_rng(seed)
+    logits = _logit_rows(seed, b, v)[rng.permutation(b)]
+    temps = np.where(np.arange(b) % 2 == 1, 0.7, 0.0).astype(np.float32)
+    top_k = np.where(np.arange(b) % 4 == 3, 50, 0).astype(np.int32)
+    top_p = np.where(np.arange(b) % 8 == 1, 1.0, 0.9).astype(np.float32)
+    # precondition: every sampled row's cut value occurs once
+    scaled = logits / np.maximum(temps, 1e-6)[:, None]
+    out = np.asarray(_filter(jnp.asarray(scaled), jnp.asarray(top_k),
+                             jnp.asarray(top_p)))
+    for i in np.nonzero(temps)[0]:
+        cut = out[i][out[i] > NEG].min()
+        assert np.count_nonzero(scaled[i] == cut) == 1, i
+    args = (jnp.asarray(logits), jnp.asarray(rng.integers(0, 4096, b),
+                                             jnp.int32),
+            jnp.asarray(rng.integers(0, 2**31, b), jnp.uint32),
+            jnp.asarray(temps), jnp.asarray(top_k), jnp.asarray(top_p))
+    np.testing.assert_array_equal(np.asarray(sample_tokens(*args)),
+                                  np.asarray(_scatter_sample_tokens(*args)))
 
 
 def test_prompt_lookup_draft():

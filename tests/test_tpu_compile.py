@@ -15,6 +15,7 @@ library; kernels are called with ``interpret=False`` because on the CPU
 backend their default is interpret mode.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,7 @@ from repro.kernels import binarize_pack as bp
 from repro.kernels import fused_bnn as fb
 from repro.kernels import paged_attention as pa
 from repro.kernels import xnor_popcount as xp
+from repro.serving.sampling import sample_tokens
 
 
 @pytest.fixture(scope="module")
@@ -40,7 +42,7 @@ def topo():
 
 
 @pytest.fixture(scope="module")
-def compile_tpu(topo):
+def compile_text(topo):
     """compile(fn, *(shape, dtype)) -> compiled text, for one v5e chip.
 
     The persistent compile cache is off meanwhile: entries written for
@@ -53,13 +55,21 @@ def compile_tpu(topo):
 
     def compile_(fn, *shapes):
         args = [jax.ShapeDtypeStruct(s, d, sharding=dev) for s, d in shapes]
-        text = jax.jit(fn).lower(*args).compile().as_text()
-        assert "tpu_custom_call" in text
-        return text
+        return jax.jit(fn).lower(*args).compile().as_text()
 
     yield compile_
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def compile_tpu(compile_text):
+    """compile_text for a program that holds a Pallas kernel."""
+    def compile_(fn, *shapes):
+        text = compile_text(fn, *shapes)
+        assert "tpu_custom_call" in text
+        return text
+    return compile_
 
 
 # qwen1.5-0.5b projections as (K, N): q/k/v/o, gate/up, down, and the
@@ -88,6 +98,24 @@ def test_xnor_popcount_compiles(compile_tpu):
     compile_tpu(lambda a, w: xp.xnor_popcount_matmul(a, w, 1024,
                                                      interpret=False),
                 ((8, 32), jnp.uint32), ((2816, 32), jnp.uint32))
+
+
+def test_sample_tokens_compiles_without_scatter(compile_text):
+    """A decode batch of 32 rows over qwen1.5-0.5b's 151936-entry
+    vocabulary: the top-k/top-p filter masks in vocabulary order, so the
+    program holds no scatter (a TPU expands one over every row's
+    vocabulary into an index sort and flattened fusions)."""
+    b, v = 32, 151936
+    text = compile_text(sample_tokens, ((b, v), jnp.float32),
+                        ((b,), jnp.int32), ((b,), jnp.uint32),
+                        ((b,), jnp.float32), ((b,), jnp.int32),
+                        ((b,), jnp.float32))
+    # every instruction's opcode: the first lower-case word after its
+    # (possibly tuple) shape that opens the operand list
+    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = .*?\s([a-z][\w-]*)\(", text,
+                     re.M)
+    assert "sort" in ops
+    assert not [op for op in ops if "scatter" in op]
 
 
 # published head counts and dims; block_size 16, 16 blocks per row

@@ -14,8 +14,8 @@ import json
 import numpy as np
 import pytest
 
-from repro.serving import (TRACE_SCHEMA_VERSION, Tracer, read_trace,
-                           replay_trace, validate_trace)
+from repro.serving import (TRACE_SCHEMA_VERSION, SamplingParams, Tracer,
+                           read_trace, replay_trace, validate_trace)
 from repro.serving.tracing import RECORD_TYPES
 from test_serving import _engine  # bnn_cfg/bnn_params from conftest.py
 
@@ -269,6 +269,41 @@ def test_span_records_carry_step_and_parent(bnn_cfg, bnn_params,
     with pytest.raises(ValueError, match="missing field 'parent'"):
         validate_trace([records[0], {"type": "span", "name": "x",
                                      "dur_s": 0.0, "step": 0}])
+
+
+def test_dispatch_spans_count_sampled_rows(bnn_cfg, bnn_params, tmp_path):
+    """Each ``<kind>.dispatch`` span's ``sampled`` is the number of its
+    call's rows with temperature > 0: the rows the sampling filter
+    decides."""
+    eng = _engine(bnn_cfg, bnn_params, max_batch=4)
+    path = str(tmp_path / "trace.jsonl")
+    eng.start_trace(path)
+    rng = np.random.default_rng(2)
+    hot = set()
+    for i in range(4):
+        sp = SamplingParams(temperature=0.7 * (i % 2), top_p=0.9, seed=i)
+        rid = eng.submit(rng.integers(0, bnn_cfg.vocab, 4 + i), 5,
+                         sampling=sp)
+        if i % 2:
+            hot.add(rid)
+    eng.run()
+    eng.stop_trace()
+    records = read_trace(path)
+    decode_rids = {r["step"]: r["decode"]["rids"] for r in records
+                   if r["type"] == "step" and "decode" in r}
+    spans = [r for r in records if r["type"] == "span"
+             and r["name"].endswith(".dispatch")]
+    assert {r["name"] for r in spans} == {"prefill.dispatch",
+                                          "decode.dispatch"}
+    mixed = False
+    for r in spans:
+        if r["name"] == "prefill.dispatch":
+            assert r["sampled"] == (r["rid"] in hot)
+        else:
+            rids = decode_rids[r["step"]]
+            assert r["sampled"] == len(hot.intersection(rids)) <= r["rows"]
+            mixed |= 0 < r["sampled"] < r["rows"]
+    assert mixed
 
 
 # ------------------------------------------------- stats == span sums
