@@ -31,6 +31,7 @@ import sys               # noqa: E402
 
 import numpy as np       # noqa: E402
 
+import loader            # noqa: E402
 import run               # noqa: E402
 import stats             # noqa: E402
 import traffic_gen       # noqa: E402
@@ -77,10 +78,10 @@ def backlog(eng, win, at: float) -> int:
 
 def sweep(cell, rates, seconds, seed):
     import jax
-    import weights
     srv = Server(cell)
     with jax.default_matmul_precision(cell.config["matmul_precision"]):
-        params = weights.make(cell.config, seed)
+        params = loader.family(cell.config, "weights").make(cell.config,
+                                                            seed)
         rows = []
         for rate in rates:
             eng = srv.engine(params)
@@ -123,8 +124,8 @@ def readings(cell, seeds, seconds, rate=None):
     limits, for the program and for the control put in its place (the
     reference in bfloat16, putting its own token first)."""
     import jax
-    import weights
     conf = cell.config
+    weights = loader.family(conf, "weights")
     rows = []
     with jax.default_matmul_precision(conf["matmul_precision"]):
         srv = Server(cell)

@@ -5,10 +5,17 @@ is 2*M*K*N operations whether it runs as XNOR-popcount on the vector
 unit or as int8 on the matrix unit, and its weight is K*N/8 bytes however
 the program stores it.  Rooflines and the whole step's share of the peak
 are read against these counts (``metrics/``).
+
+What holds for any family is here; what depends on the layers is asked
+of the configuration's family (``families/<family>/costs.py``, found by
+``loader.family``): the (K, N) of each binarized projection of a layer,
+the float operations per token, and the sequence mixer's work.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
+
+import loader
 
 
 @dataclass
@@ -30,13 +37,21 @@ def bnn_gemm(m: int, k: int, n: int, act_bytes: int = 4) -> Work:
 
 def projections(c: dict) -> list[tuple[int, int]]:
     """(K, N) of every binarized projection of one layer."""
-    d = c["d_model"]
-    if c["family"] == "dense":
-        hq, hkv, dh, f = c["n_heads"], c["n_kv_heads"], c["head_dim"], \
-            c["d_ff"]
-        return [(d, hq * dh), (d, hkv * dh), (d, hkv * dh), (hq * dh, d),
-                (d, f), (d, f), (f, d)]
-    raise ValueError(f"no projection list for family {c['family']!r}")
+    return loader.family(c, "costs").projections(c)
+
+
+def float_ops(c: dict, tokens: int) -> float:
+    """Float operations besides the mixer's products for ``tokens`` rows."""
+    return loader.family(c, "costs").float_ops(c, tokens)
+
+
+def mixer(c: dict, rows: list[tuple[int, int]]) -> Work:
+    """The sequence mixer's work over ``rows`` of (new tokens, context
+    after them), in every layer: paged attention in the dense family."""
+    return loader.family(c, "costs").mixer(c, rows)
+
+
+attention = mixer       # the dense family's mixer, as its readers name it
 
 
 def gemms(c: dict, tokens: int) -> Work:
@@ -47,38 +62,12 @@ def gemms(c: dict, tokens: int) -> Work:
     return Work(w.ops * c["n_layers"], w.bytes * c["n_layers"])
 
 
-def attention(c: dict, rows: list[tuple[int, int]],
-              kv_bytes: int = 4) -> Work:
-    """Paged attention over ``rows`` of (new tokens, context after them):
-    each new token attends to every position up to its own (QK and PV,
-    2 operations a multiply-add each); the K and V of each row's context
-    are read once, the queries and outputs once, in every layer."""
-    hq, hkv, dh = c["n_heads"], c["n_kv_heads"], c["head_dim"]
-    ops = byt = 0.0
-    for q_len, ctx in rows:
-        start = ctx - q_len
-        pairs = q_len * start + q_len * (q_len + 1) / 2
-        ops += 4.0 * hq * dh * pairs
-        byt += 2.0 * ctx * hkv * dh * kv_bytes + 2.0 * q_len * hq * dh * 4
-    return Work(ops * c["n_layers"], byt * c["n_layers"])
-
-
-def float_ops(c: dict, tokens: int) -> float:
-    """Float operations besides attention's products for ``tokens`` rows:
-    the tied head, the norms, the gates and the rotary embedding."""
-    d, v, n_l = c["d_model"], c["vocab"], c["n_layers"]
-    per_token = 2.0 * d * v + 4.0 * d
-    per_layer = 8.0 * d + 4.0 * c["d_ff"] \
-        + 6.0 * (c["n_heads"] + c["n_kv_heads"]) * c["head_dim"]
-    return tokens * (per_token + n_l * per_layer)
-
-
 def step_least_s(c: dict, rows: list[tuple[int, int]], peaks: dict) -> float:
     """The least time of one step at the chip's peaks: the binarized
     GEMMs' operations at the int8 peak plus every float operation
-    (attention included) at the bf16 peak."""
+    (the mixer's included) at the bf16 peak."""
     tokens = sum(q for q, _ in rows)
-    f = float_ops(c, tokens) + attention(c, rows).ops
+    f = float_ops(c, tokens) + mixer(c, rows).ops
     return gemms(c, tokens).ops / peaks["int8_ops_per_s"] \
         + f / peaks["bf16_flops_per_s"]
 

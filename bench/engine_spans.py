@@ -12,11 +12,13 @@ width), decode and spec spans ``rows`` and ``bucket``.
 
 Device side: the step programs put ``jax.named_scope`` names on their
 pieces (``attn``, ``kv_update``, ``ffn``, ``head``, ``sample``,
-``weight_pack``) and ``name=`` on their Pallas kernels.  XLA keeps the
-scope path as each HLO instruction's ``op_name``; the profiler stores
-each program's HLO in its ``/host:metadata`` plane, which is read here
-with a few lines of protobuf wire decoding, and an op is matched to its
-instruction through the program run ("XLA Modules" event) that holds it.
+``weight_pack``) and ``name=`` on their Pallas kernels, which names
+each call's instruction (``paged_attention.45``; ``kernel_calls``).
+XLA keeps the scope path as each HLO instruction's ``op_name``; the
+profiler stores each program's HLO in its ``/host:metadata`` plane,
+which is read here with a few lines of protobuf wire decoding, and an
+op is matched to its instruction through the program run ("XLA
+Modules" event) that holds it.
 (On a TPU v5e the "XLA Ops" events carry no scope of their own: an
 event's name is the instruction's HLO text, ``%copy.12 = f32[...]
 copy(...)``, and its stats are times.)
@@ -276,25 +278,57 @@ def op_paths(ops: list, modules: list,
     return out
 
 
+def kernel_calls(ops: list, names) -> list:
+    """The Pallas kernel calls among ``ops`` made by a kernel of one of
+    ``names`` (its ``name=``; XLA numbers each call, ``name.<n>``)."""
+    return [ev for ev in ops if "tpu_custom_call" in ev.label
+            and instruction(ev).rsplit(".", 1)[0] in names]
+
+
 def in_scope(path: str, scope: str) -> bool:
     return scope in path.split("/")
 
 
-def whole_pool_copy(ev, path: str, pool: str) -> bool:
-    """An XLA-inserted copy of a whole KV pool that carries no scope:
-    counted with the pool update (``kv_update``)."""
-    return (instruction(ev).startswith("copy") and pool in ev.label
+def hlo_type(dtype) -> str:
+    """HLO's name of a dtype: ``f32``, ``bf16``, ``s8``, ``pred``."""
+    name = str(dtype)
+    if name == "bool":
+        return "pred"
+    for long, short in (("bfloat", "bf"), ("float", "f"), ("uint", "u"),
+                        ("int", "s")):
+        if name.startswith(long):
+            return short + name[len(long):]
+    return name
+
+
+def pool_labels(leaves) -> list[str]:
+    """The label text of each distinct shape among the engine's state
+    pools (``f32[2561,16,16,64]``), as an op's label spells an operand."""
+    out = []
+    for a in leaves:
+        lab = f"{hlo_type(a.dtype)}[{','.join(str(d) for d in a.shape)}]"
+        if lab not in out:
+            out.append(lab)
+    return out
+
+
+def whole_pool_copy(ev, path: str, pools) -> bool:
+    """An XLA-inserted copy of a whole state pool (one of the label
+    texts ``pools``) that carries no scope: counted with the pool update
+    (``kv_update``)."""
+    return (instruction(ev).startswith("copy")
+            and any(p in ev.label for p in pools)
             and not any(in_scope(path, s) for s in SCOPES))
 
 
 def scope_time(ops: list, paths: list[str], scope: str,
-               pool: str | None = None) -> float:
-    """Device seconds of the ops in ``scope``; with ``pool``, plus the
-    unscoped whole-pool copies."""
+               pools=()) -> float:
+    """Device seconds of the ops in ``scope``, plus the unscoped copies
+    of a whole pool of ``pools``."""
     t = 0.0
     for ev, path in zip(ops, paths):
         if in_scope(path, scope) or (
-                pool is not None and whole_pool_copy(ev, path, pool)):
+                pools and whole_pool_copy(ev, path, pools)):
             t += ev.dur
     return t
 
@@ -389,18 +423,11 @@ def idle_ms_per_step(ctx, phase: str) -> float | None:
     return 1e3 * got["idle"].get(phase, 0.0) / got["steps"]
 
 
-def pool_shape(ctx) -> str:
-    """The label text of one KV pool of the cell: (blocks, block size,
-    KV heads, head size) in float32."""
-    e, c = ctx.cell.spec["engine"], ctx.cell.config
-    return (f"f32[{e['num_blocks']},{e['block_size']},{c['n_kv_heads']},"
-            f"{c['head_dim']}]")
-
-
 def scope_ms_per_step(ctx, scope: str, with_pool_copies: bool = False
                       ) -> float | None:
     """Device time of the ops in ``scope``, ms per step (with the
-    unscoped whole-pool copies when asked)."""
+    unscoped copies of a whole pool the engine holds, ``ctx.pools``,
+    when asked)."""
     got = _loaded(ctx)
     if got is None or not ctx.trace.ops:
         return None
@@ -412,6 +439,6 @@ def scope_ms_per_step(ctx, scope: str, with_pool_copies: bool = False
         got["paths"] = paths if scoped else []
     if not got["paths"]:
         return None
-    pool = pool_shape(ctx) if with_pool_copies else None
-    return 1e3 * scope_time(ctx.trace.ops, got["paths"], scope, pool) \
+    pools = ctx.pools if with_pool_copies else ()
+    return 1e3 * scope_time(ctx.trace.ops, got["paths"], scope, pools) \
         / got["steps"]

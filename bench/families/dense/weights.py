@@ -113,7 +113,7 @@ def _jitted(frozen: tuple):
 
 def make(cfg: dict, seed: int) -> dict:
     """The weight tree of configuration ``cfg`` for ``seed``."""
-    if cfg["family"] != "dense" or not cfg.get("tie_embeddings", False):
+    if not cfg.get("tie_embeddings", False):
         raise ValueError("only dense stacks with tied embeddings are built")
     frozen = tuple(sorted((k, v) for k, v in cfg.items()
                           if isinstance(v, (int, float, str, bool))))

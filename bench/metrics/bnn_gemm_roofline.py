@@ -3,22 +3,22 @@
 Least time: per program run, every projection's 2*M*K*N operations at
 the int8 peak or its bytes (packed weight, alpha, float activations in,
 float32 out) at HBM bandwidth, whichever is larger (``costs.gemms``),
-with M the real rows fed.  Kernel time: the device time of the fused
-binarize-pack-XNOR-popcount kernel and of the weight-pack kernel: every
-``tpu_custom_call`` op that is not paged attention (the 1-bit model has
-no other Pallas kernel; see ``paged_attn_roofline.py``)."""
+with M the real rows fed.  Kernel time: the device time of the
+XNOR-popcount GEMM kernel (``bnn_xnor_gemm``) and of the binarize-pack
+kernel (``bnn_binarize_pack``), found by the kernels' names
+(``engine_spans.kernel_calls``)."""
 import costs
+import engine_spans
+
+NAMES = ("bnn_xnor_gemm", "bnn_binarize_pack")
 
 
 def read(ctx):
-    import run
     import trace_reduce
     if ctx.trace is None or not ctx.peaks:
         return None
-    attn = run.load_reader("paged_attn_roofline")
-    t = trace_reduce.device_time(
-        [ev for ev in ctx.trace.ops if "tpu_custom_call" in ev.label
-         and not attn.is_kernel(ev.label, ctx)])
+    t = trace_reduce.device_time(engine_spans.kernel_calls(ctx.trace.ops,
+                                                           NAMES))
     c, p = ctx.cell.config, ctx.peaks
     least = 0.0
     for st in ctx.window.traced_steps:

@@ -3,29 +3,21 @@
 Least time: per program run, the QK and PV operations over each row's
 true context at the bf16 peak, or the K and V of that context in the
 pool's float32 plus queries and outputs at HBM bandwidth, whichever is
-larger (``costs.attention``).  Kernel time: the paged-attention kernel's
-device time.
-
-The kernels carry no name of their own in the trace: a Pallas kernel is
-a ``tpu_custom_call`` op named after the jitted step that holds it.  The
-paged-attention kernel is the one that takes the cell's KV pools, whose
-shape (blocks, block size, KV heads, head size) its label spells."""
+larger (``costs.attention``).  Kernel time: the device time of the
+``paged_attention`` kernel's calls, found by the kernel's name
+(``engine_spans.kernel_calls``)."""
 import costs
+import engine_spans
 
-
-def is_kernel(label: str, ctx) -> bool:
-    e, c = ctx.cell.spec["engine"], ctx.cell.config
-    pool = (f"f32[{e['num_blocks']},{e['block_size']},{c['n_kv_heads']},"
-            f"{c['head_dim']}]")
-    return "tpu_custom_call" in label and pool in label
+NAMES = ("paged_attention",)
 
 
 def read(ctx):
     import trace_reduce
     if ctx.trace is None or not ctx.peaks:
         return None
-    t = trace_reduce.device_time(
-        [ev for ev in ctx.trace.ops if is_kernel(ev.label, ctx)])
+    t = trace_reduce.device_time(engine_spans.kernel_calls(ctx.trace.ops,
+                                                           NAMES))
     c, p = ctx.cell.config, ctx.peaks
     least = 0.0
     for st in ctx.window.traced_steps:

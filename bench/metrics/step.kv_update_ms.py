@@ -2,9 +2,9 @@
 
 The ops of the step programs in the ``kv_update`` scope (the scatter of
 new keys and values into the paged pools), plus every XLA-inserted copy
-whose output is a whole KV pool of the cell and that carries no scope
-(``engine_spans.whole_pool_copy``), over the window's ``engine.step``
-spans."""
+of a whole state pool that the built engine holds (``ctx.pools``) and
+that carries no scope (``engine_spans.whole_pool_copy``), over the
+window's ``engine.step`` spans."""
 import engine_spans
 
 

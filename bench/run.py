@@ -6,9 +6,13 @@
 The cell (``BENCHMARK.json``) names a configuration (``configs/<name>.json``)
 and a traffic mix (``traffic/<name>.json``); the cell's own file
 (``workloads/<cell>.json``) holds its fixed rate, its engine sizes and the
-limits of its correctness check.  A run:
+limits of its correctness check.  The configuration's ``family`` names
+the directory (``families/<family>/``) that brings its seeded weights,
+its plain reference and its work counts, and the mix names its arrival
+process (``arrivals/<process>.py``): ``loader.py`` finds each by name.
+A run:
 
-1. builds the seeded weights on the device in one jitted call, one
+1. builds the family's seeded weights on the device in one jitted call, one
    ``Engine`` with the cell's sizes, and warms up the prefill chunk and
    every decode bucket the cell uses (set-up);
 2. offers the cell's open-loop traffic, a lead-in (set-up) and then a
@@ -17,8 +21,8 @@ limits of its correctness check.  A run:
 3. reads the device's peak memory and the window's numbers, keeps the
    engine stepping (untimed, no arrivals) until enough greedy requests
    have finished (``drain``), frees the engine, and teacher-forces a
-   seeded sample of the finished greedy requests through the plain
-   reference (``reference.py``): ``correct`` holds when no served token's
+   seeded sample of the finished greedy requests through the family's
+   plain reference: ``correct`` holds when no served token's
    reference logit lies further below the reference's best than the
    cell's limit;
 4. prints one JSON line: the cell's end-to-end metrics with ``--trace 0``,
@@ -37,7 +41,6 @@ T_PROCESS = time.perf_counter()        # set-up is timed from here
 import argparse                        # noqa: E402
 import contextlib                      # noqa: E402
 import gc                              # noqa: E402
-import importlib.util                  # noqa: E402
 import json                            # noqa: E402
 import os                              # noqa: E402
 import shutil                          # noqa: E402
@@ -50,14 +53,13 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
+import engine_spans                    # noqa: E402
+import loader                          # noqa: E402
 import stats                           # noqa: E402
 import traffic_gen                     # noqa: E402
+from loader import BenchError          # noqa: E402
 
 OUT_DIR = os.path.join(ROOT, ".bench_out")
-
-
-class BenchError(RuntimeError):
-    """A run that cannot produce a result."""
 
 
 def log(msg: str):
@@ -405,7 +407,7 @@ def served_gaps(params, conf: dict, req: dict, dtype=None) -> dict:
     below float32) also ``control_gap``: the same for the token that the
     reference in that dtype puts first."""
     import jax.numpy as jnp
-    import reference
+    reference = loader.family(conf, "reference")
     seq = np.concatenate([req["prompt"], req["served"]])
     p = len(req["prompt"])
     toks = np.zeros(ref_bucket(len(seq) - 1), np.int32)
@@ -462,13 +464,9 @@ def judge(numbers: dict) -> bool:
 # ---------------------------------------------------------------- metrics
 
 
-def load_reader(name: str, root: str = HERE):
-    path = os.path.join(root, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def load_reader(name: str):
+    """The reader ``metrics/<name>.py`` of a per-layer metric."""
+    return loader.module(os.path.join(loader.BENCH, "metrics", name + ".py"))
 
 
 def end_to_end(cell: Cell, win: Window, setup_s: float) -> dict:
@@ -501,6 +499,7 @@ class LayerContext:
     peaks: dict
     peak_bytes: int
     requests: dict               # rid -> the engine's Request
+    pools: list[str]             # label text of each state pool's shape
 
 
 def per_layer(ctx: LayerContext) -> dict:
@@ -555,11 +554,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
 def _run(cell, seed, seconds, trace, t_process, clock, conf, devices, dev,
          peaks, cache, fault, control):
     import jax
-    import weights
     from repro.serving import Engine
     spec, mix = cell.spec, cell.mix
     cfg = arch_config(conf)
-    params = weights.make(conf, seed)
+    params = loader.family(conf, "weights").make(conf, seed)
     jax.block_until_ready(params)
     check_params(params, cfg)
     ecfg = engine_config(spec)
@@ -567,6 +565,7 @@ def _run(cell, seed, seconds, trace, t_process, clock, conf, devices, dev,
         raise BenchError("max_model_len is shorter than the mix's longest "
                          "request")
     eng = Engine(params, cfg, ecfg)
+    pools = engine_spans.pool_labels(jax.tree.leaves(eng.cache.pools))
     warm_up(eng, cfg.vocab)
     if fault is not None:
         fault(eng)
@@ -595,7 +594,7 @@ def _run(cell, seed, seconds, trace, t_process, clock, conf, devices, dev,
             raise BenchError("the profiler wrote no trace")
         reduced = trace_reduce.load(path)
     ctx = LayerContext(cell, win, reduced, peaks, peak_bytes,
-                       dict(eng.requests))
+                       dict(eng.requests), pools)
     if trace:
         metrics = per_layer(ctx)
     else:

@@ -1,5 +1,8 @@
 """A tiny copy of a benchmark cell for the CPU tests: the cell's own
-files, with every width and length cut so that a run takes seconds."""
+files, with every width and length cut so that a run takes seconds.
+The widths come from the cell's family (``families/<family>/tiny.json``),
+the cells from ``BENCHMARK.json``, so a new cell is tested as it comes."""
+import json
 import os
 import sys
 
@@ -10,16 +13,21 @@ for p in (BENCH, os.path.join(ROOT, "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+import loader  # noqa: E402
 import run  # noqa: E402
 
-QWEN_SMALL = dict(n_layers=4, d_model=128, vocab=512, n_heads=4,
-                  n_kv_heads=4, head_dim=32, d_ff=256)
-SMALL = {"qwen-bnn.chat-steady": QWEN_SMALL, "qwen-bnn.rag-long": QWEN_SMALL}
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    CELLS = [w["name"] for w in json.load(_f)["workloads"]]
 
 
-def cell(name="qwen-bnn.chat-steady"):
-    c = run.load_cell(name)
-    small = SMALL[name]
+def tiny_widths(conf: dict) -> dict:
+    with open(os.path.join(loader.family_dir(conf), "tiny.json")) as f:
+        return json.load(f)
+
+
+def cut(c):
+    """Cut a loaded cell to its tiny copy, in place; returns it."""
+    small = tiny_widths(c.config)
     c.config = dict(c.config, **small)
     c.config["changed"] = dict(c.config["changed"], **small)
     c.mix = dict(c.mix,
@@ -36,6 +44,10 @@ def cell(name="qwen-bnn.chat-steady"):
                             max_tokens_in_flight=512)
     c.spec["check"] = dict(c.spec["check"], sample_tokens=64)
     return c
+
+
+def cell(name=CELLS[0]):
+    return cut(run.load_cell(name))
 
 
 def serve(c, seed=2 ** 33 + 11, seconds=4.0, trace=False, fault=None,

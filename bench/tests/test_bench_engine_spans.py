@@ -94,7 +94,7 @@ def test_scope_time_counts_unscoped_whole_pool_copies_as_kv_update():
     assert paths[3] == "jit(_prefill)/sample/sort"
     # copy.5 carries no scope: counted with the pool update; copy.6 is
     # the update's own scatter
-    assert S.scope_time(tr.ops, paths, "kv_update", POOL) == \
+    assert S.scope_time(tr.ops, paths, "kv_update", [POOL]) == \
         pytest.approx(0.25)
     assert S.scope_time(tr.ops, paths, "kv_update") == pytest.approx(0.05)
     assert S.scope_time(tr.ops, paths, "sample") == pytest.approx(0.1)
@@ -113,7 +113,7 @@ def test_scope_time_counts_unscoped_whole_pool_copies_as_kv_update():
 def ctx_for(tr, requests=None, window=None):
     return types.SimpleNamespace(
         cell=run.load_cell("qwen-bnn.rag-long"), trace=tr,
-        requests=requests or {}, window=window)
+        requests=requests or {}, window=window, pools=[POOL])
 
 
 @pytest.fixture

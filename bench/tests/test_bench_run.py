@@ -13,8 +13,8 @@ import pytest
 import bench_tiny
 import run
 
-CELL = "qwen-bnn.chat-steady"
-CELLS = ["qwen-bnn.chat-steady", "qwen-bnn.rag-long"]
+CELL = bench_tiny.CELLS[0]
+CELLS = bench_tiny.CELLS
 
 
 @pytest.fixture(scope="module")
@@ -110,6 +110,9 @@ def _state_unchanged(eng):
 
 
 def _half_batch(eng):
+    """Every family's decode program takes (params, pools, tokens, table,
+    lengths, active, slots, sampling...): rows off in ``active`` are
+    left out as padding is."""
     dec = eng._decode_fn
 
     def f(params, pools, tokens, table, lengths, active, *a):

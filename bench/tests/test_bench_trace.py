@@ -71,18 +71,60 @@ def recorded():
 
 
 def test_recorded_trace_kernels_by_operand_shapes():
+    """A trace from before the kernels carried names: the shape rule
+    (``record_trace.shape_rules``) finds paged attention by the KV pool
+    among its operands, and the name rule finds nothing to read."""
     import types
 
+    import engine_spans
+    import record_trace
     import run
     tr = recorded()
-    ctx = types.SimpleNamespace(cell=run.load_cell("qwen-bnn.rag-long"),
-                                trace=tr)
-    attn = run.load_reader("paged_attn_roofline")
+    cell = run.load_cell("qwen-bnn.rag-long")
+    ctx = types.SimpleNamespace(cell=cell, trace=tr)
     kernels = [e for e in tr.ops if "tpu_custom_call" in e.label]
-    paged = [e for e in kernels if attn.is_kernel(e.label, ctx)]
+    by_shape = record_trace.shape_rules(tr.ops, cell)
+    paged = by_shape["attention"]
     # one paged-attention call per layer in each of the two steps
     assert len(paged) == 2 * 24
     assert T.device_time(paged) == pytest.approx(0.075564, rel=1e-4)
-    assert len(kernels) - len(paged) == 672
+    assert len(kernels) - len(paged) == len(by_shape["bnn"]) == 672
+    assert engine_spans.kernel_calls(tr.ops, record_trace.ATTN +
+                                     record_trace.BNN) == []
     ms = run.load_reader("step.decode_ms").read(ctx)
     assert ms == pytest.approx(142.877, rel=1e-4)
+
+
+NAMED = ["qwen-bnn.chat-steady", "qwen-bnn.rag-long"]
+
+
+@pytest.mark.parametrize("cell", NAMED)
+def test_chip_trace_name_rules_select_what_shape_rules_did(cell):
+    """One decode and one prefill program run of the cell on a TPU v5e,
+    reduced by ``record_trace.py``: the kernels found by name and the
+    pool copies found by the engine's own pools are the ops that the
+    dense KV pool's shape found, with the same device time."""
+    import gzip
+    import os
+
+    import engine_spans as S
+    import record_trace as R
+    import run
+    path = os.path.join(bench_tiny.HERE, "data", f"named_{cell}.json.gz")
+    with gzip.open(path, "rt") as f:
+        d = json.load(f)
+    tr = T.from_json(d)
+    c = run.load_cell(cell)
+    old, new = R.shape_rules(tr.ops, c), R.name_rules(tr.ops)
+    # one paged-attention call per layer in each of the two programs
+    assert len(new["attention"]) == 2 * c.config["n_layers"]
+    assert new["bnn"]
+    for kind in ("attention", "bnn"):
+        assert new[kind] == old[kind], kind
+        assert T.device_time(new[kind]) == T.device_time(old[kind])
+    assert d["pools"] == [R.dense_pool(c)]
+    copies = R.pool_copies(tr.ops, d["paths"], d["pools"])
+    assert copies
+    assert copies == R.pool_copies(tr.ops, d["paths"], [R.dense_pool(c)])
+    assert S.scope_time(tr.ops, d["paths"], "kv_update", d["pools"]) > \
+        S.scope_time(tr.ops, d["paths"], "kv_update")

@@ -9,13 +9,17 @@ import numpy as np
 import pytest
 
 import bench_tiny
-import reference
-import weights
+import loader
+
+DENSE = {"family": "dense"}
+reference = loader.family(DENSE, "reference")
+weights = loader.family(DENSE, "weights")
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    conf = bench_tiny.cell().config
+    conf = bench_tiny.cell("qwen-bnn.chat-steady").config
+    assert conf["family"] == "dense"
     return conf, weights.make(conf, 2 ** 33 + 7)
 
 

@@ -12,7 +12,9 @@ the schedule a window serves, which is all a cell above its knee
 reaches, is the same work for every seed too.
 
 A mix file holds:
-  arrivals  {"process": "poisson"}
+  arrivals  {"process": <name>, ...}: the process ``arrivals/<name>.py``
+            (``loader.arrivals``) with the rest of the entry as its
+            parameters; ``poisson`` takes none
   prompt    a length distribution (below) for prompts of unique tokens
   output    a length distribution for the tokens generated
   shared    optional {"share", "documents", "doc_len", "question"}: that
@@ -32,6 +34,8 @@ from dataclasses import dataclass
 from statistics import NormalDist
 
 import numpy as np
+
+import loader
 
 
 @dataclass
@@ -67,13 +71,13 @@ def stratified(values: np.ndarray, rng: np.random.Generator,
     return np.asarray(values)[np.asarray(order, np.int64)]
 
 
-def _quantiles(n: int) -> np.ndarray:
+def quantiles(n: int) -> np.ndarray:
     return (np.arange(n) + 0.5) / n
 
 
 def lengths(spec: dict, n: int) -> np.ndarray:
     """``n`` lengths at evenly spaced quantiles of ``spec``, ascending."""
-    q = _quantiles(n)
+    q = quantiles(n)
     if spec["dist"] == "lognormal":
         z = np.array([NormalDist().inv_cdf(float(p)) for p in q])
         v = spec["median"] * np.exp(spec["sigma"] * z)
@@ -84,22 +88,16 @@ def lengths(spec: dict, n: int) -> np.ndarray:
     return np.clip(np.rint(v), spec["min"], spec["max"]).astype(np.int64)
 
 
-def _exp_gaps(n: int, mean: float) -> np.ndarray:
-    return -mean * np.log1p(-_quantiles(n))
-
-
 def arrival_times(spec: dict, rate: float, horizon_s: float,
                   rng: np.random.Generator) -> np.ndarray:
-    """Arrival times in [0, horizon_s) at mean ``rate`` per second: the
-    same number for every seed, from gaps at fixed quantiles in a seeded
-    order."""
+    """Arrival times in [0, horizon_s) at mean ``rate`` per second, from
+    the mix's process: the same number for every seed, in an order the
+    seed chooses."""
     if rate <= 0:
         raise ValueError("rate must be positive")
     n = max(1, int(round(rate * horizon_s)))
-    if spec["process"] == "poisson":
-        gaps = stratified(_exp_gaps(n, 1.0 / rate), rng)
-        return np.cumsum(gaps) * horizon_s / (gaps.sum() + 1.0 / rate)
-    raise ValueError(f"unknown arrival process {spec['process']!r}")
+    return loader.arrivals(spec["process"]).times(spec, n, rate, horizon_s,
+                                                  rng)
 
 
 def schedule(mix: dict, rate: float, horizon_s: float, vocab: int,
