@@ -52,6 +52,11 @@ class ArchConfig:
     ssm_conv: int = 4
     attn_period: int = 0             # hybrid: 1 attention layer per period
     attn_offset: int = 0             # index within the period that is attn
+    ssd_precision: str = "highest"   # the SSD's contractions (a lax
+                                     # Precision name): at "default" a TPU
+                                     # rounds float32 operands to bfloat16
+                                     # and the carried state, a sum over
+                                     # the sequence, loses its low bits
     # misc
     norm: str = "rmsnorm"
     norm_eps: float = 1e-6

@@ -9,6 +9,20 @@ what makes the ``long_500k`` cell tractable for SSM/hybrid archs.
 
 Shapes per block: x (B, T, d_model); d_inner = expand * d_model;
 heads H = d_inner / headdim P; state N = d_state; groups G (=1 here).
+
+As ``mamba_ssm/modules/mamba2.py`` computes it: the gate multiplies
+before the norm (``RMSNormGated``, ``norm_before_gate=False``):
+``out_proj(rmsnorm(y * silu(z)))``, at the configuration's ``norm_eps``.
+
+Every contraction of the SSD runs at the configuration's
+``ssd_precision`` ("highest" unless a configuration says otherwise): on
+a TPU a float32 einsum at the default precision rounds its operands to
+bfloat16, and the carried state is a sum over the whole sequence, so it
+would lose its low bits every chunk.
+
+Named scopes (the benchmark reads device time by them): ``ssd`` holds
+the conv, the scan or dual form and the gated norm; ``ssm_state`` the
+gathers and scatters of the serving slot pools and the snapshot copies.
 """
 from __future__ import annotations
 
@@ -18,6 +32,14 @@ import jax.numpy as jnp
 from repro.layers import common as C
 
 Array = jax.Array
+
+def _einsum(cfg, spec: str, *ops) -> Array:
+    return jnp.einsum(spec, *ops, precision=cfg.ssd_precision)
+
+
+def _gated_norm(y: Array, z: Array, params, eps: float) -> Array:
+    """``RMSNormGated`` with the gate before the norm."""
+    return C.rmsnorm(y * jax.nn.silu(z).astype(y.dtype), params, eps)
 
 
 def init(key, cfg, dtype=jnp.float32):
@@ -69,82 +91,83 @@ def forward(params, cfg, x: Array, *, chunk: int = 256,
     """Full-sequence SSD (train/prefill)."""
     bsz, t, _ = x.shape
     zxbcdt = C.dense(x, params["in_proj"], precision)
-    z, xbc, dt, d_inner, h, g, n = _split_proj(cfg, zxbcdt)
-    p = cfg.ssm_headdim
-    xbc = _causal_conv(xbc, params["conv_w"], params["conv_b"])
-    xs, b_, c_ = jnp.split(xbc, [d_inner, d_inner + g * n], axis=-1)
-    xs = xs.reshape(bsz, t, h, p)
-    b_ = b_.reshape(bsz, t, g, n)
-    c_ = c_.reshape(bsz, t, g, n)
+    with jax.named_scope("ssd"):
+        z, xbc, dt, d_inner, h, g, n = _split_proj(cfg, zxbcdt)
+        p = cfg.ssm_headdim
+        xbc = _causal_conv(xbc, params["conv_w"], params["conv_b"])
+        xs, b_, c_ = jnp.split(xbc, [d_inner, d_inner + g * n], axis=-1)
+        xs = xs.reshape(bsz, t, h, p)
+        b_ = b_.reshape(bsz, t, g, n)
+        c_ = c_.reshape(bsz, t, g, n)
 
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"][None, None])
-    a = -jnp.exp(params["A_log"].astype(jnp.float32))        # (H,)
-    log_decay = dt * a[None, None, :]                         # (B,T,H) = log a_t
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"][None, None])
+        a = -jnp.exp(params["A_log"].astype(jnp.float32))        # (H,)
+        log_decay = dt * a[None, None, :]                         # (B,T,H) = log a_t
 
-    # pad T to chunk multiple
-    lpad = (-t) % chunk
-    if lpad:
-        xs = jnp.pad(xs, ((0, 0), (0, lpad), (0, 0), (0, 0)))
-        b_ = jnp.pad(b_, ((0, 0), (0, lpad), (0, 0), (0, 0)))
-        c_ = jnp.pad(c_, ((0, 0), (0, lpad), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, lpad), (0, 0)))
-        log_decay = jnp.pad(log_decay, ((0, 0), (0, lpad), (0, 0)))
-    tp = t + lpad
-    nc = tp // chunk
+        # pad T to chunk multiple
+        lpad = (-t) % chunk
+        if lpad:
+            xs = jnp.pad(xs, ((0, 0), (0, lpad), (0, 0), (0, 0)))
+            b_ = jnp.pad(b_, ((0, 0), (0, lpad), (0, 0), (0, 0)))
+            c_ = jnp.pad(c_, ((0, 0), (0, lpad), (0, 0), (0, 0)))
+            dt = jnp.pad(dt, ((0, 0), (0, lpad), (0, 0)))
+            log_decay = jnp.pad(log_decay, ((0, 0), (0, lpad), (0, 0)))
+        tp = t + lpad
+        nc = tp // chunk
 
-    def ch(v, *trail):
-        return v.reshape(bsz, nc, chunk, *trail)
+        def ch(v, *trail):
+            return v.reshape(bsz, nc, chunk, *trail)
 
-    xs_c = ch(xs, h, p)
-    b_c = ch(b_, g, n)
-    c_c = ch(c_, g, n)
-    dt_c = ch(dt, h)
-    ld_c = ch(log_decay, h)
+        xs_c = ch(xs, h, p)
+        b_c = ch(b_, g, n)
+        c_c = ch(c_, g, n)
+        dt_c = ch(dt, h)
+        ld_c = ch(log_decay, h)
 
-    cum = jnp.cumsum(ld_c, axis=2)                            # (B,nc,L,H)
-    total = cum[:, :, -1]                                     # (B,nc,H)
+        cum = jnp.cumsum(ld_c, axis=2)                            # (B,nc,L,H)
+        total = cum[:, :, -1]                                     # (B,nc,H)
 
-    # ---- intra-chunk (quadratic / attention-dual form) ----
-    # M[t,s] = (C_t . B_s) * exp(cum_t - cum_s) for s <= t
-    cb = jnp.einsum("bclgn,bcsgn->bclsg", c_c, b_c)           # (B,nc,L,L,G)
-    cb = cb[..., 0]                                           # G=1 -> (B,nc,L,L)
-    seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]       # (B,nc,L,L,H)
-    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-    # mask BEFORE exp: for s > t the exponent is positive and can
-    # overflow; exp(inf)*0 would poison the backward pass with NaNs.
-    seg = jnp.where(causal[None, None, :, :, None], seg, -1e30)
-    decay = jnp.exp(seg)
-    m = cb[..., None] * decay                                 # (B,nc,L,L,H)
-    xdt = xs_c * dt_c[..., None]                              # (B,nc,L,H,P)
-    y_intra = jnp.einsum("bclsh,bcshp->bclhp", m, xdt)
+        # ---- intra-chunk (quadratic / attention-dual form) ----
+        # M[t,s] = (C_t . B_s) * exp(cum_t - cum_s) for s <= t
+        cb = _einsum(cfg, "bclgn,bcsgn->bclsg", c_c, b_c)              # (B,nc,L,L,G)
+        cb = cb[..., 0]                                           # G=1 -> (B,nc,L,L)
+        seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]       # (B,nc,L,L,H)
+        causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+        # mask BEFORE exp: for s > t the exponent is positive and can
+        # overflow; exp(inf)*0 would poison the backward pass with NaNs.
+        seg = jnp.where(causal[None, None, :, :, None], seg, -1e30)
+        decay = jnp.exp(seg)
+        m = cb[..., None] * decay                                 # (B,nc,L,L,H)
+        xdt = xs_c * dt_c[..., None]                              # (B,nc,L,H,P)
+        y_intra = _einsum(cfg, "bclsh,bcshp->bclhp", m, xdt)
 
-    # ---- chunk boundary states ----
-    # S_c = sum_s exp(total - cum_s) * dt_s * B_s (x) x_s   -> (B,nc,H,N,P)
-    w_s = jnp.exp(total[:, :, None, :] - cum) * dt_c          # (B,nc,L,H)
-    states = jnp.einsum("bclh,bclgn,bclhp->bchnp",
-                        w_s, b_c, xs_c)                       # g=1 folded
+        # ---- chunk boundary states ----
+        # S_c = sum_s exp(total - cum_s) * dt_s * B_s (x) x_s   -> (B,nc,H,N,P)
+        w_s = jnp.exp(total[:, :, None, :] - cum) * dt_c          # (B,nc,L,H)
+        states = _einsum(cfg, "bclh,bclgn,bclhp->bchnp",
+                         w_s, b_c, xs_c)                          # g=1 folded
 
-    # ---- inter-chunk associative scan over (decay, state) ----
-    decay_c = jnp.exp(total)                                  # (B,nc,H)
+        # ---- inter-chunk associative scan over (decay, state) ----
+        decay_c = jnp.exp(total)                                  # (B,nc,H)
 
-    def combine(left, right):
-        dl, sl = left
-        dr, sr = right
-        return dl * dr, sr + sl * dr[..., None, None]
+        def combine(left, right):
+            dl, sl = left
+            dr, sr = right
+            return dl * dr, sr + sl * dr[..., None, None]
 
-    dprod, sprefix = jax.lax.associative_scan(combine, (decay_c, states), axis=1)
-    # state entering chunk c = prefix of chunks < c
-    h_prev = jnp.concatenate(
-        [jnp.zeros_like(sprefix[:, :1]), sprefix[:, :-1]], axis=1)
+        dprod, sprefix = jax.lax.associative_scan(combine, (decay_c, states), axis=1)
+        # state entering chunk c = prefix of chunks < c
+        h_prev = jnp.concatenate(
+            [jnp.zeros_like(sprefix[:, :1]), sprefix[:, :-1]], axis=1)
 
-    # ---- inter-chunk contribution ----
-    y_inter = jnp.einsum("bclgn,bchnp->bclhp", c_c, h_prev) * \
-        jnp.exp(cum)[..., None]
+        # ---- inter-chunk contribution ----
+        y_inter = _einsum(cfg, "bclgn,bchnp->bclhp", c_c, h_prev) * \
+            jnp.exp(cum)[..., None]
 
-    y = (y_intra + y_inter).reshape(bsz, tp, h, p)[:, :t]
-    y = y + xs[:, :t] * params["D"].astype(jnp.float32)[None, None, :, None]
-    y = y.reshape(bsz, t, d_inner).astype(x.dtype)
-    y = C.rmsnorm(y, params["norm"]) * jax.nn.silu(z)
+        y = (y_intra + y_inter).reshape(bsz, tp, h, p)[:, :t]
+        y = y + xs[:, :t] * params["D"].astype(jnp.float32)[None, None, :, None]
+        y = y.reshape(bsz, t, d_inner).astype(x.dtype)
+        y = _gated_norm(y, z, params["norm"], cfg.norm_eps)
     return C.dense(y, params["out_proj"], precision)
 
 
@@ -164,32 +187,33 @@ def decode_step(params, cfg, x: Array, cache, *,
     """O(1) single-token step. x: (B, 1, d_model)."""
     bsz = x.shape[0]
     zxbcdt = C.dense(x, params["in_proj"], precision)
-    z, xbc, dt, d_inner, h, g, n = _split_proj(cfg, zxbcdt)
-    p = cfg.ssm_headdim
+    with jax.named_scope("ssd"):
+        z, xbc, dt, d_inner, h, g, n = _split_proj(cfg, zxbcdt)
+        p = cfg.ssm_headdim
 
-    # conv with cached history
-    hist = jnp.concatenate([cache["conv"], xbc], axis=1)       # (B, k, C)
-    w = params["conv_w"]
-    out = jnp.sum(hist * w[None], axis=1, keepdims=True)
-    xbc1 = jax.nn.silu(out + params["conv_b"][None, None])
-    new_conv = hist[:, 1:]
+        # conv with cached history
+        hist = jnp.concatenate([cache["conv"], xbc], axis=1)       # (B, k, C)
+        w = params["conv_w"]
+        out = jnp.sum(hist * w[None], axis=1, keepdims=True)
+        xbc1 = jax.nn.silu(out + params["conv_b"][None, None])
+        new_conv = hist[:, 1:]
 
-    xs, b_, c_ = jnp.split(xbc1, [d_inner, d_inner + g * n], axis=-1)
-    xs = xs.reshape(bsz, h, p)
-    b_ = b_.reshape(bsz, n)
-    c_ = c_.reshape(bsz, n)
-    dt = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + params["dt_bias"][None])
-    a = -jnp.exp(params["A_log"].astype(jnp.float32))
-    decay = jnp.exp(dt * a[None])                              # (B,H)
+        xs, b_, c_ = jnp.split(xbc1, [d_inner, d_inner + g * n], axis=-1)
+        xs = xs.reshape(bsz, h, p)
+        b_ = b_.reshape(bsz, n)
+        c_ = c_.reshape(bsz, n)
+        dt = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + params["dt_bias"][None])
+        a = -jnp.exp(params["A_log"].astype(jnp.float32))
+        decay = jnp.exp(dt * a[None])                              # (B,H)
 
-    hstate = cache["h"].astype(jnp.float32)
-    upd = jnp.einsum("bh,bn,bhp->bhnp", dt, b_.astype(jnp.float32),
-                     xs.astype(jnp.float32))
-    hstate = hstate * decay[:, :, None, None] + upd
-    y = jnp.einsum("bn,bhnp->bhp", c_.astype(jnp.float32), hstate)
-    y = y + xs.astype(jnp.float32) * params["D"].astype(jnp.float32)[None, :, None]
-    y = y.reshape(bsz, 1, d_inner).astype(x.dtype)
-    y = C.rmsnorm(y, params["norm"]) * jax.nn.silu(z)
+        hstate = cache["h"].astype(jnp.float32)
+        upd = _einsum(cfg, "bh,bn,bhp->bhnp", dt, b_.astype(jnp.float32),
+                      xs.astype(jnp.float32))
+        hstate = hstate * decay[:, :, None, None] + upd
+        y = _einsum(cfg, "bn,bhnp->bhp", c_.astype(jnp.float32), hstate)
+        y = y + xs.astype(jnp.float32) * params["D"].astype(jnp.float32)[None, :, None]
+        y = y.reshape(bsz, 1, d_inner).astype(x.dtype)
+        y = _gated_norm(y, z, params["norm"], cfg.norm_eps)
     out = C.dense(y, params["out_proj"], precision)
     return out, {"h": hstate.astype(cache["h"].dtype), "conv": new_conv}
 
@@ -225,23 +249,26 @@ def copy_slot(dst_pool: dict, dst: Array, src_pool: dict,
     primitive (serving/mixer_state.py jits this with the destination
     pool donated: store writes a live slot into the snapshot pool,
     restore writes a snapshot row back into the live pool)."""
-    return {k: v.at[dst].set(src_pool[k][src].astype(v.dtype))
-            for k, v in dst_pool.items()}
+    with jax.named_scope("ssm_state"):
+        return {k: v.at[dst].set(src_pool[k][src].astype(v.dtype))
+                for k, v in dst_pool.items()}
 
 
 def snapshot_slots(cache, slots: Array) -> dict:
     """Device-side copy of each row's recurrent slot — taken BEFORE a
     multi-token verify so a partially-rejected speculative step can be
     rolled back (restore + re-advance by the accepted prefix only)."""
-    return {k: v[slots] for k, v in cache.items()}
+    with jax.named_scope("ssm_state"):
+        return {k: v[slots] for k, v in cache.items()}
 
 
 def restore_slots(cache, slots: Array, snap: dict) -> dict:
     """Write per-row snapshots back into the slot pool (the speculative
     rollback).  Rows sharing the scratch slot all rewrite the same
     scratch snapshot, so duplicate indices are harmless."""
-    return {k: v.at[slots].set(snap[k].astype(v.dtype))
-            for k, v in cache.items()}
+    with jax.named_scope("ssm_state"):
+        return {k: v.at[slots].set(snap[k].astype(v.dtype))
+                for k, v in cache.items()}
 
 
 def paged_decode_step(params, cfg, x: Array, cache, slots: Array, *,
@@ -249,14 +276,13 @@ def paged_decode_step(params, cfg, x: Array, cache, slots: Array, *,
                       active: Array | None = None) -> tuple[Array, dict]:
     """O(1) decode against the slot pool.  x (B, 1, d); slots (B,) slot
     ids (padded rows masked to scratch slot 0 by ``active``)."""
-    state = {"h": cache["h"][slots], "conv": cache["conv"][slots]}
+    with jax.named_scope("ssm_state"):
+        state = {"h": cache["h"][slots], "conv": cache["conv"][slots]}
     y, new = decode_step(params, cfg, x, state, precision=precision)
     dst = slots if active is None else jnp.where(active, slots, 0)
-    cache = {
-        "h": cache["h"].at[dst].set(new["h"].astype(cache["h"].dtype)),
-        "conv": cache["conv"].at[dst].set(
-            new["conv"].astype(cache["conv"].dtype)),
-    }
+    with jax.named_scope("ssm_state"):
+        cache = {k: v.at[dst].set(new[k].astype(v.dtype))
+                 for k, v in cache.items()}
     return y, cache
 
 
@@ -281,61 +307,64 @@ def prefill_chunk(params, cfg, x: Array, cache, slots: Array,
     positions true no-ops.
     """
     bsz, c_len, _ = x.shape
+    with jax.named_scope("ssm_state"):
+        h0 = cache["h"][slots].astype(jnp.float32)             # (B,H,N,P)
+        hist = cache["conv"][slots]                            # (B, k-1, ch)
     zxbcdt = C.dense(x, params["in_proj"], precision)
-    z, xbc, dt, d_inner, h, g, n = _split_proj(cfg, zxbcdt)
-    p = cfg.ssm_headdim
-    k = params["conv_w"].shape[0]
+    with jax.named_scope("ssd"):
+        z, xbc, dt, d_inner, h, g, n = _split_proj(cfg, zxbcdt)
+        p = cfg.ssm_headdim
+        k = params["conv_w"].shape[0]
 
-    # depthwise causal conv with the slot's carried (k-1)-token tail
-    hist = cache["conv"][slots].astype(xbc.dtype)              # (B, k-1, ch)
-    full = jnp.concatenate([hist, xbc], axis=1)                # (B, k-1+C, ch)
-    out = sum(full[:, i:i + c_len] * params["conv_w"][i][None, None]
-              for i in range(k))
-    xbc1 = jax.nn.silu(out + params["conv_b"][None, None])
-    # new tail = last k-1 inputs up to the row's valid length
-    idx = n_valid[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None, :]
-    new_conv = jnp.take_along_axis(full, idx[:, :, None], axis=1)
+        # depthwise causal conv with the slot's carried (k-1)-token tail
+        full = jnp.concatenate([hist.astype(xbc.dtype), xbc], axis=1)
+        out = sum(full[:, i:i + c_len] * params["conv_w"][i][None, None]
+                  for i in range(k))
+        xbc1 = jax.nn.silu(out + params["conv_b"][None, None])
+        # new tail = last k-1 inputs up to the row's valid length
+        idx = n_valid[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None, :]
+        new_conv = jnp.take_along_axis(full, idx[:, :, None], axis=1)
 
-    xs, b_, c_ = jnp.split(xbc1, [d_inner, d_inner + g * n], axis=-1)
-    xs = xs.reshape(bsz, c_len, h, p).astype(jnp.float32)
-    b_ = b_.reshape(bsz, c_len, n).astype(jnp.float32)         # g = 1
-    c_ = c_.reshape(bsz, c_len, n).astype(jnp.float32)
+        xs, b_, c_ = jnp.split(xbc1, [d_inner, d_inner + g * n], axis=-1)
+        xs = xs.reshape(bsz, c_len, h, p).astype(jnp.float32)
+        b_ = b_.reshape(bsz, c_len, n).astype(jnp.float32)         # g = 1
+        c_ = c_.reshape(bsz, c_len, n).astype(jnp.float32)
 
-    valid = jnp.arange(c_len, dtype=jnp.int32)[None, :] < n_valid[:, None]
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"][None, None])
-    dt = dt * valid[..., None]                                 # (B,C,H)
-    a = -jnp.exp(params["A_log"].astype(jnp.float32))
-    log_decay = dt * a[None, None, :]
-    cum = jnp.cumsum(log_decay, axis=1)                        # (B,C,H)
-    total = cum[:, -1]                                         # (B,H)
+        valid = jnp.arange(c_len, dtype=jnp.int32)[None, :] < n_valid[:, None]
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"][None, None])
+        dt = dt * valid[..., None]                                 # (B,C,H)
+        a = -jnp.exp(params["A_log"].astype(jnp.float32))
+        log_decay = dt * a[None, None, :]
+        cum = jnp.cumsum(log_decay, axis=1)                        # (B,C,H)
+        total = cum[:, -1]                                         # (B,H)
 
-    # intra-chunk quadratic (attention-dual) form
-    cb = jnp.einsum("bln,bsn->bls", c_, b_)
-    seg = cum[:, :, None, :] - cum[:, None, :, :]              # (B,C,C,H)
-    causal = jnp.tril(jnp.ones((c_len, c_len), bool))
-    seg = jnp.where(causal[None, :, :, None], seg, -1e30)
-    m = cb[..., None] * jnp.exp(seg)
-    xdt = xs * dt[..., None]                                   # (B,C,H,P)
-    y = jnp.einsum("blsh,bshp->blhp", m, xdt)
+        # intra-chunk quadratic (attention-dual) form
+        cb = _einsum(cfg, "bln,bsn->bls", c_, b_)
+        seg = cum[:, :, None, :] - cum[:, None, :, :]              # (B,C,C,H)
+        causal = jnp.tril(jnp.ones((c_len, c_len), bool))
+        seg = jnp.where(causal[None, :, :, None], seg, -1e30)
+        m = cb[..., None] * jnp.exp(seg)
+        xdt = xs * dt[..., None]                                   # (B,C,H,P)
+        y = _einsum(cfg, "blsh,bshp->blhp", m, xdt)
 
-    # carried-state contribution + new boundary state
-    h0 = cache["h"][slots].astype(jnp.float32)                 # (B,H,N,P)
-    y = y + jnp.einsum("bln,bhnp->blhp", c_, h0) * jnp.exp(cum)[..., None]
-    w_s = jnp.exp(total[:, None, :] - cum) * dt                # (B,C,H)
-    states = jnp.einsum("blh,bln,blhp->bhnp", w_s, b_, xs)
-    hstate = h0 * jnp.exp(total)[:, :, None, None] + states
+        # carried-state contribution + new boundary state
+        y = y + _einsum(cfg, "bln,bhnp->blhp", c_, h0) * jnp.exp(cum)[..., None]
+        w_s = jnp.exp(total[:, None, :] - cum) * dt                # (B,C,H)
+        states = _einsum(cfg, "blh,bln,blhp->bhnp", w_s, b_, xs)
+        hstate = h0 * jnp.exp(total)[:, :, None, None] + states
 
-    y = y + xs * params["D"].astype(jnp.float32)[None, None, :, None]
-    y = y.reshape(bsz, c_len, d_inner).astype(x.dtype)
-    y = C.rmsnorm(y, params["norm"]) * jax.nn.silu(z)
+        y = y + xs * params["D"].astype(jnp.float32)[None, None, :, None]
+        y = y.reshape(bsz, c_len, d_inner).astype(x.dtype)
+        y = _gated_norm(y, z, params["norm"], cfg.norm_eps)
     out = C.dense(y, params["out_proj"], precision)
 
     dst = jnp.where(n_valid > 0, slots, 0)
-    cache = {
-        "h": cache["h"].at[dst].set(hstate.astype(cache["h"].dtype)),
-        "conv": cache["conv"].at[dst].set(
-            new_conv.astype(cache["conv"].dtype)),
-    }
+    with jax.named_scope("ssm_state"):
+        cache = {
+            "h": cache["h"].at[dst].set(hstate.astype(cache["h"].dtype)),
+            "conv": cache["conv"].at[dst].set(
+                new_conv.astype(cache["conv"].dtype)),
+        }
     return out, cache
 
 
@@ -356,9 +385,9 @@ def forward_reference(params, cfg, x: Array) -> Array:
 
     def step(hs, inp):
         xt, bt, ct, dct, dtt = inp
-        upd = jnp.einsum("bh,bn,bhp->bhnp", dtt, bt, xt)
+        upd = _einsum(cfg, "bh,bn,bhp->bhnp", dtt, bt, xt)
         hs = hs * dct[:, :, None, None] + upd
-        yt = jnp.einsum("bn,bhnp->bhp", ct, hs)
+        yt = _einsum(cfg, "bn,bhnp->bhp", ct, hs)
         return hs, yt
 
     h0 = jnp.zeros((bsz, h, n, p), jnp.float32)
@@ -371,5 +400,5 @@ def forward_reference(params, cfg, x: Array) -> Array:
     y = ys.transpose(1, 0, 2, 3)
     y = y + xs.astype(jnp.float32) * params["D"].astype(jnp.float32)[None, None, :, None]
     y = y.reshape(bsz, t, d_inner).astype(x.dtype)
-    y = C.rmsnorm(y, params["norm"]) * jax.nn.silu(z)
+    y = _gated_norm(y, z, params["norm"], cfg.norm_eps)
     return C.dense(y, params["out_proj"], "bf16")

@@ -141,12 +141,14 @@ class MixerState(abc.ABC):
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _slot_zero(pool, slot):
-    return {k: v.at[slot].set(0.0) for k, v in pool.items()}
+    with jax.named_scope("ssm_state"):
+        return {k: v.at[slot].set(0.0) for k, v in pool.items()}
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _slot_restore(pool, slot, host):
-    return {k: v.at[slot].set(host[k]) for k, v in pool.items()}
+    with jax.named_scope("ssm_state"):
+        return {k: v.at[slot].set(host[k]) for k, v in pool.items()}
 
 
 # store AND restore are the same device-to-device row copy with the

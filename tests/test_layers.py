@@ -143,6 +143,8 @@ class _SsmCfg:
     ssm_headdim = 8
     ssm_state = 16
     ssm_conv = 4
+    norm_eps = 1e-5
+    ssd_precision = "highest"
 
 
 def test_ssd_chunked_vs_recurrence_vs_decode():

@@ -72,13 +72,19 @@ def compile_tpu(compile_text):
     return compile_
 
 
-# qwen1.5-0.5b projections as (K, N): q/k/v/o, gate/up, down, and the
-# 151936-wide (tied) head width
-QWEN_GEMMS = [(1024, 1024), (1024, 2816), (2816, 1024), (1024, 151936)]
+# (K, N) of the binarized projections: qwen1.5-0.5b's q/k/v/o, gate/up
+# and down; mamba2-1.3b's in_proj 2048 -> 8512 (z, x, B, C, dt) and
+# out_proj 4096 -> 2048 (packed as their transposes, K up to 4096)
+PROJECTIONS = [(1024, 1024), (1024, 2816), (2816, 1024), (2048, 8512),
+               (4096, 2048)]
+# the tied heads' widths: qwen's 151936 and mamba2's 50288
+HEADS = [(1024, 151936), (2048, 50288)]
 
 
-@pytest.mark.parametrize("m", [8, 64], ids=["decode8", "prefill64"])
-@pytest.mark.parametrize("k,n", QWEN_GEMMS)
+@pytest.mark.parametrize("m", [8, 16, 64, 256],
+                         ids=["decode8", "decode16", "prefill64",
+                              "prefill256"])
+@pytest.mark.parametrize("k,n", PROJECTIONS + HEADS)
 def test_fused_bnn_compiles(compile_tpu, m, k, n):
     kw = -(-k // 32)
     compile_tpu(
@@ -87,7 +93,7 @@ def test_fused_bnn_compiles(compile_tpu, m, k, n):
         ((m, k), jnp.float32), ((n, kw), jnp.uint32), ((n,), jnp.float32))
 
 
-@pytest.mark.parametrize("k,n", QWEN_GEMMS[:3])
+@pytest.mark.parametrize("k,n", PROJECTIONS)
 def test_binarize_pack_weight_transpose_compiles(compile_tpu, k, n):
     """bnn_dense packs w.T (N, K) inside every jitted step."""
     compile_tpu(lambda w: bp.binarize_pack(w.T, interpret=False),
@@ -169,26 +175,20 @@ KERNEL_NAMES = ("paged_attention", "bnn_xnor_gemm", "bnn_binarize_pack")
 SCOPES = ("attn", "kv_update", "ffn", "head", "sample", "weight_pack")
 
 
-@pytest.fixture(scope="module")
-def step_programs(topo, compile_tpu, request):
-    """Compiled text of the engine's decode (bucket 8) and prefill (chunk
-    64) programs for one v5e chip, with the Pallas kernels selected as
-    on the chip (the platform is reported as "tpu" while tracing)."""
+def _step_program_text(topo, request, cfg, ecfg) -> dict[str, str]:
+    """Compiled text of the engine's decode (bucket ``max_batch``) and
+    prefill (one chunk) programs of ``cfg`` for one v5e chip, with the
+    Pallas kernels selected as on the chip (the platform is reported as
+    "tpu" while tracing)."""
     import numpy as np
 
-    from repro import configs
     from repro.models import transformer as M
-    from repro.serving import EngineConfig
     from repro.serving import roles as R
 
     mp = pytest.MonkeyPatch()
     request.addfinalizer(mp.undo)
     mp.setattr(jax, "default_backend", lambda: "tpu")
     dev = SingleDeviceSharding(topo.devices[0])
-    cfg = configs.get_config("qwen1.5-0.5b").replace(n_layers=1,
-                                                     precision="bnn")
-    ecfg = EngineConfig(block_size=16, num_blocks=65, max_batch=8,
-                        prefill_chunk=64, max_model_len=256)
     fns = R.build_step_fns(cfg, ecfg, R.get_role("mixed"), ring=False,
                            spec_k=0)
 
@@ -196,9 +196,10 @@ def step_programs(topo, compile_tpu, request):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=dev), tree)
 
+    b = ecfg.max_batch
     params = spec(M.abstract_init(cfg)[0])
     pools = spec(jax.eval_shape(lambda: M.init_paged_state(
-        cfg, ecfg.num_blocks, ecfg.block_size)))
+        cfg, ecfg.num_blocks, ecfg.block_size, b + 1)))
     mb = ecfg.max_model_len // ecfg.block_size
 
     def rows(b):
@@ -210,12 +211,33 @@ def step_programs(topo, compile_tpu, request):
 
     text = {}
     text["decode"] = fns.decode.lower(
-        params, pools, i32(8, 1), i32(8, mb), i32(8), spec(np.zeros(8, bool)),
-        i32(8), *rows(8)).compile().as_text()
+        params, pools, i32(b, 1), i32(b, mb), i32(b), spec(np.zeros(b, bool)),
+        i32(b), *rows(b)).compile().as_text()
     text["prefill"] = fns.prefill.lower(
         params, pools, i32(1, ecfg.prefill_chunk), i32(1, mb), i32(1),
         i32(1), i32(1), *rows(1)).compile().as_text()
     return text
+
+
+def _op_names(text: str) -> set[str]:
+    """Every path element of the instructions' ``op_name``s."""
+    names = set()
+    for line in text.splitlines():
+        if 'op_name="' in line:
+            names.update(line.split('op_name="', 1)[1].split('"', 1)[0]
+                         .split("/"))
+    return names
+
+
+@pytest.fixture(scope="module")
+def step_programs(topo, compile_tpu, request):
+    from repro import configs
+    from repro.serving import EngineConfig
+    cfg = configs.get_config("qwen1.5-0.5b").replace(n_layers=1,
+                                                     precision="bnn")
+    ecfg = EngineConfig(block_size=16, num_blocks=65, max_batch=8,
+                        prefill_chunk=64, max_model_len=256)
+    return _step_program_text(topo, request, cfg, ecfg)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -228,10 +250,23 @@ def test_step_programs_carry_kernel_names_and_scopes(step_programs,
              if 'custom_call_target="tpu_custom_call"' in line]
     for name in KERNEL_NAMES:
         assert any(c.startswith(name) for c in calls), (name, calls)
-    op_names = set()
-    for line in text.splitlines():
-        if 'op_name="' in line:
-            op_names.update(line.split('op_name="', 1)[1].split('"', 1)[0]
-                            .split("/"))
+    op_names = _op_names(text)
     for scope in SCOPES:
         assert scope in op_names, scope
+
+
+def test_ssm_step_programs_carry_ssd_and_state_scopes(topo, compile_tpu,
+                                                      request):
+    """mamba2-1.3b's decode (bucket 16) and prefill (chunk 256) programs,
+    one layer at published widths, compile for one v5e chip, and their
+    instructions carry the SSD's scope and the slot state's."""
+    from repro import configs
+    from repro.serving import EngineConfig
+    cfg = configs.get_config("mamba2-1.3b").replace(n_layers=1,
+                                                    precision="bnn")
+    ecfg = EngineConfig(max_batch=16, prefill_chunk=256, max_model_len=1408)
+    for program, text in _step_program_text(topo, request, cfg,
+                                            ecfg).items():
+        assert 'custom_call_target="tpu_custom_call"' in text, program
+        assert {"ssd", "ssm_state", "attn", "weight_pack"} \
+            <= _op_names(text), program
